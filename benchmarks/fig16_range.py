@@ -14,14 +14,18 @@ law of the tier:
     RANGE throughput never exceeds ONE shard's — flat in n_shards.  That gap
     is the reason the range-partitioned tier exists.
 
-A third leg, ``fig16/mesh/...``, runs the same scatter-gather RANGE wave on
-a REAL multi-device mesh: a subprocess forces XLA's host platform to expose
-4 devices (the kv_dryrun trick — CI machines have one) and times the
-``rangeshard.range_wave_sharded`` shard_map program end to end, reporting
-measured MOPS against the perfmodel roofline for that shard count.
+A third leg, ``fig16/mesh_cpu/...``, runs the same scatter-gather RANGE
+wave on a multi-device mesh of CPU devices: a subprocess with
+``JAX_PLATFORMS=cpu`` forces XLA's host platform to expose 4 devices (the
+kv_dryrun trick) and times the ``rangeshard.range_wave_sharded`` shard_map
+program end to end.  It always runs on the CPU — on a chip machine the
+parent process already holds the chip — so its times are CPU wall clock,
+not device numbers; ``chip_smoke.py --chips 4`` runs the same wave on four
+chips.
 """
 
 import json
+import os
 import subprocess
 import sys
 
@@ -40,10 +44,8 @@ LIMITS = (10, 100)
 WAVE = 1024
 MESH_SHARDS = 4
 
-# runs in a fresh interpreter: XLA_FLAGS must be set before jax imports
+# runs in a fresh interpreter (env set before jax imports): CPU devices only
 _MESH_SCRIPT = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import json, sys, time
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh
@@ -87,9 +89,13 @@ print(json.dumps({
 
 
 def _run_mesh_leg():
-    """Time the scatter-gather RANGE wave on a real (forced) 4-device mesh
+    """Time the scatter-gather RANGE wave on a forced 4-device CPU mesh
     and emit measured-vs-roofline cells; errors surface as a module failure
     (the harness keeps sweeping, the smoke gate records it)."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["XLA_FLAGS"] = (
+        env.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=4"
+    ).strip()
     n = 4000 if common.SMOKE else 20000
     w = 256 if common.SMOKE else 1024
     repeats = 2 if common.SMOKE else 4
@@ -110,6 +116,7 @@ def _run_mesh_leg():
             capture_output=True,
             text=True,
             timeout=600,
+            env=env,
         )
         if proc.returncode != 0:
             raise RuntimeError(f"mesh leg failed:\n{proc.stderr[-2000:]}")
@@ -117,7 +124,7 @@ def _run_mesh_leg():
         assert res["n_devices"] >= MESH_SHARDS, res
         roof = perfmodel.range_mops(res["depth"], limit=limit) * MESH_SHARDS
         emit(
-            f"fig16/mesh/shards{MESH_SHARDS}/limit{limit}",
+            f"fig16/mesh_cpu/shards{MESH_SHARDS}/limit{limit}",
             res["wave_us"] / w,
             f"measured_mops={res['measured_mops']:.3e};"
             f"model_mops={roof:.1f};"

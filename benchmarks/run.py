@@ -594,6 +594,9 @@ def main(argv=None) -> None:
         help="artifact path for --smoke (default: BENCH_smoke.json)",
     )
     args = parser.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from . import common
 

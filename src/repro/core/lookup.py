@@ -1,9 +1,10 @@
-"""Pure-jnp batched traversal of the NIC-side learned index (reference path).
+"""Pure-jnp batched traversal of the NIC-side learned index (the served path).
 
-This module is the *semantic definition* of the DPA traverser (Sec 3.1): the
-Pallas kernels in ``repro.kernels`` are tile-level implementations of exactly
-these functions and are tested against them.  On CPU (this container) the ops
-layer dispatches here; on TPU it dispatches to the kernels.
+This module is the *semantic definition* of the DPA traverser (Sec 3.1) and
+the path the store serves on every backend, the TPU included: nothing on the
+served path imports ``repro.kernels``.  The Pallas kernels there are
+tile-level versions of these functions, tested against them in interpret
+mode; they do not lower for TPU yet (``tests/test_tpu_compile.py``).
 
 Access-pattern faithfulness: each inner-node visit touches (1) the segment
 first-key line, (2) the segment model, (3) an eps-bounded pivot window, and

@@ -1,9 +1,10 @@
 """Analytic performance model of DPA-Store on BlueField-3 (Sec 4.2.6).
 
-This container has no BlueField-3 (or TPU), so absolute MOPS numbers cannot
-be *measured*; the paper itself, however, derives its throughput from a
-memory-access model and shows the measurement matches (27.2 -> 31.05 model
-vs 33 measured MOPS).  We implement that model exactly, parameterised by the
+The model stands in for BlueField-3 hardware, which this project has no
+access to; it is not a model of the TPU, whose times come only from runs
+on the chip (``chip_smoke.py``).  The paper itself derives its throughput
+from a memory-access model and shows the measurement matches (27.2 -> 31.05
+model vs 33 measured MOPS).  We implement that model exactly, parameterised by the
 same hardware constants (Chen et al. [6] / paper Sec 2.3):
 
     DPA memory access   465 ns
@@ -16,7 +17,8 @@ Counted quantities (lines/DMAs per op) come from the *implemented* data
 structures — ``count_get_accesses`` mirrors lookup.py line for line — so if
 the implementation changes shape, the model moves with it.  The benchmarks
 assert the paper's numbers against this model (reproduction) and report the
-CPU-measured wave throughput separately (sanity, not a claim).
+wall-clock wave throughput of whatever device ran them separately (sanity,
+not a claim).
 """
 
 from __future__ import annotations

@@ -71,35 +71,55 @@ def shard_of_np(keys_u64: np.ndarray, n_shards: int) -> np.ndarray:
     )
 
 
-def _pad_stack(arrs):
+def _pad_stack(arrs, sharding: Optional[NamedSharding] = None):
     """Stack per-shard pool arrays, zero-padding every dim to the max shape
-    so vmap/shard_map can treat the shard dim uniformly."""
-    if arrs[0].ndim == 0:
-        return jnp.stack(arrs)
+    so vmap/shard_map can treat the shard dim uniformly.
+
+    With a ``sharding`` (``P("data")`` over a mesh with one device per
+    shard) each shard's padded block stays on its own device and the
+    blocks are assembled in place — no device ever holds the whole stack."""
     shape = tuple(max(a.shape[i] for a in arrs) for i in range(arrs[0].ndim))
-    return jnp.stack(
-        [
-            jnp.pad(a, [(0, shape[i] - a.shape[i]) for i in range(a.ndim)])
-            for a in arrs
-        ]
-    )
+
+    def block(a):
+        pad = [(0, shape[i] - a.shape[i]) for i in range(a.ndim)]
+        return (jnp.pad(a, pad) if a.ndim else a)[None]
+
+    if sharding is None:
+        return jnp.concatenate([block(a) for a in arrs])
+    gshape = (len(arrs),) + shape
+    # commit each shard's array to its device first: an uncommitted array
+    # (one made under ``jax.default_device``) would be padded on the
+    # default device, which then transiently holds every shard's pools
+    placed = [
+        block(jax.device_put(arrs[idx[0].start or 0], dev))
+        for dev, idx in sharding.addressable_devices_indices_map(gshape).items()
+    ]
+    return jax.make_array_from_single_device_arrays(gshape, sharding, placed)
 
 
-def stack_shards(stores) -> Tuple[DeviceTree, InsertBuffers, int]:
+def stack_shards(
+    stores, mesh: Optional[Mesh] = None
+) -> Tuple[DeviceTree, InsertBuffers, int]:
     """Stack per-shard device trees + insert buffers along a leading shard
     dim (pool shapes padded to the max).  Returns (tree, ib, depth); all
-    shards must have equal depth for the lockstep traversal."""
+    shards must have equal depth for the lockstep traversal.
+
+    ``mesh`` (one device per shard on its ``data`` axis) assembles the
+    stack with ``NamedSharding(mesh, P("data"))``: shard ``s``'s pools land
+    on the mesh's ``s``-th device, which is where a store built under
+    ``jax.default_device`` of that device already keeps them."""
+    sharding = None if mesh is None else NamedSharding(mesh, P("data"))
     tree_t = type(stores[0].tree)
     stacked_tree = tree_t(
         **{
-            f: _pad_stack([getattr(st.tree, f) for st in stores])
+            f: _pad_stack([getattr(st.tree, f) for st in stores], sharding)
             for f in tree_t._fields
         }
     )
     ib_t = type(stores[0].ib)
     stacked_ib = ib_t(
         **{
-            f: _pad_stack([getattr(st.ib, f) for st in stores])
+            f: _pad_stack([getattr(st.ib, f) for st in stores], sharding)
             for f in ib_t._fields
         }
     )
@@ -1819,8 +1839,6 @@ def serve_wave_sharded(
     ``route_fn_prev`` is given (a live ownership handoff),
     fn(stacked_tree, stacked_ib, khi, klo, epoch_tag) with per-request
     admitted-epoch tags (see :func:`make_serve_wave`)."""
-    from jax.experimental.shard_map import shard_map
-
     n_shards = mesh.shape["data"]
     body = make_serve_wave(
         n_shards, cap, depth=depth, eps_inner=eps_inner, eps_leaf=eps_leaf,
@@ -1840,14 +1858,14 @@ def serve_wave_sharded(
         return tuple(o[None] for o in out)
 
     state_specs = jax.tree.map(lambda _: P("data"), (stacked_tree, stacked_ib))
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard,
         mesh=mesh,
         in_specs=(
             state_specs[0], state_specs[1], P("data"), P("data"), P("data"),
         ),
         out_specs=(P("data"), P("data"), P("data"), P("data")),
-        check_rep=False,
+        check_vma=False,
     )
     if route_fn_prev is not None:
         return fn  # caller supplies per-request epoch tags

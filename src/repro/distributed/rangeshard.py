@@ -473,8 +473,6 @@ def range_wave_sharded(
     dim; outputs match ``range_wave_emulated`` (8 outputs incl. the
     per-shard ``rounds``).
     """
-    from jax.experimental.shard_map import shard_map
-
     n_shards = mesh.shape["data"]
     F = n_shards if fanout is None else fanout
     (bp_hi, bp_lo), (bc_hi, bc_lo) = _epoch_inputs(boundaries, boundaries_prev)
@@ -525,7 +523,7 @@ def range_wave_sharded(
         return tuple(o[None] for o in outs) + (rounds[None],)
 
     state_specs = jax.tree.map(lambda _: P("data"), (stacked_tree, stacked_ib))
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard,
         mesh=mesh,
         in_specs=(
@@ -536,7 +534,7 @@ def range_wave_sharded(
             P("data"),
         ),
         out_specs=tuple(P("data") for _ in range(8)),
-        check_rep=False,
+        check_vma=False,
     )
     if boundaries_prev is not None:
         return fn  # caller supplies per-request epoch tags

@@ -117,10 +117,14 @@ def generic_probe_pallas(
     salts_bloom: Sequence[int],
     salt_bucket: int,
     block_requests: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Batched payload-generic probe: (hit (B,), payload (B, P)).  The one
-    kernel both cache families instantiate (see module docstring)."""
+    kernel both cache families instantiate (see module docstring).
+
+    Not on the served path (the store probes through ``cacheset.probe_set``),
+    and it does not lower for TPU yet: Mosaic refuses its bucket gather
+    (``tests/test_tpu_compile.py``)."""
     B = khi.shape[0]
     assert B % block_requests == 0
     P = bpay.shape[3]
@@ -166,7 +170,7 @@ def probe_pallas(
     *,
     cfg: CacheConfig,
     block_requests: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Point-GET hot-entry probe: value-payload (P=2) instantiation of the
     generic kernel.  Semantics == hotcache.probe."""
@@ -196,7 +200,7 @@ def anchor_probe_pallas(
     *,
     cfg: ScanCacheConfig,
     block_requests: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Scan-anchor probe: leaf-id-payload (P=1) instantiation of the
     generic kernel.  Semantics == scancache.probe."""

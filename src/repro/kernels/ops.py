@@ -1,16 +1,18 @@
-"""jit'd dispatch layer over the Pallas kernels.
+"""Dispatch layer over the Pallas kernels, used by the kernel tests.
 
-``impl='auto'`` selects the Pallas kernels on TPU backends and the pure-jnp
-reference path on CPU (this container), so the same store code runs in both
-worlds.  ``impl='pallas_interpret'`` forces the kernel bodies through the
-Pallas interpreter — that is what the correctness sweeps use.
+Nothing on the served path imports this module: the store serves through
+the ``jnp`` walks of ``core/lookup.py``, ``core/hotcache.py`` and
+``core/scancache.py``.  Every entry point takes a required ``impl`` —
+``"ref"`` (those ``jnp`` walks), ``"pallas"`` (the compiled kernel) or
+``"pallas_interpret"`` (the kernel body in the Pallas interpreter, which
+is what the equivalence sweeps run).  Nothing is chosen by backend: none
+of the three kernels lowers for TPU yet (``tests/test_tpu_compile.py``).
 """
 
 from __future__ import annotations
 
 from typing import Tuple
 
-import jax
 import jax.numpy as jnp
 
 from repro.core import lookup
@@ -25,8 +27,8 @@ from .range_scan import range_pallas
 
 
 def _resolve(impl: str) -> str:
-    if impl == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "ref"
+    if impl not in ("ref", "pallas", "pallas_interpret"):
+        raise ValueError(f"unknown impl {impl!r}")
     return impl
 
 
@@ -48,7 +50,7 @@ def get(
     depth: int,
     eps_inner: int,
     eps_leaf: int,
-    impl: str = "auto",
+    impl: str,
     block_requests: int = 128,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     impl = _resolve(impl)
@@ -79,7 +81,7 @@ def cache_probe(
     klo,
     *,
     cfg: CacheConfig,
-    impl: str = "auto",
+    impl: str,
     block_requests: int = 128,
 ):
     impl = _resolve(impl)
@@ -107,7 +109,7 @@ def scan_anchor_probe(
     klo,
     *,
     cfg: ScanCacheConfig,
-    impl: str = "auto",
+    impl: str,
     block_requests: int = 128,
 ):
     """Scan-anchor cache probe: (hit, leaf) — the RANGE descent-skip path."""
@@ -139,7 +141,7 @@ def range_scan(
     eps_inner: int,
     limit: int,
     max_leaves: int = 4,
-    impl: str = "auto",
+    impl: str,
     block_requests: int = 64,
     start_leaf=None,
 ):
@@ -215,7 +217,7 @@ def range_scan_loop(
     limit: int,
     max_leaves: int = 4,
     max_rounds: int = 0,
-    impl: str = "auto",
+    impl: str,
     block_requests: int = 64,
     start_leaf=None,
     ub_hi=None,

@@ -28,7 +28,7 @@ def gather_pallas(
     pool: jnp.ndarray,  # (N, bs, H, hd)
     slots: jnp.ndarray,  # (n,) i32
     *,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     n = slots.shape[0]
     _, bs, H, hd = pool.shape
@@ -49,11 +49,13 @@ def gather_ref(pool: jnp.ndarray, slots: jnp.ndarray) -> jnp.ndarray:
     return pool[slots]
 
 
-def gather(pool, slots, impl: str = "auto"):
+def gather(pool, slots, *, impl: str):
+    """``impl``: ``"ref"``, ``"pallas"`` or ``"pallas_interpret"`` — the
+    caller names the implementation; nothing is chosen by backend."""
+    if impl not in ("ref", "pallas", "pallas_interpret"):
+        raise ValueError(f"unknown impl {impl!r}")
     if slots.shape[0] == 0:
         return jnp.zeros((0,) + pool.shape[1:], pool.dtype)
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
     if impl == "ref":
         return gather_ref(pool, slots)
     return gather_pallas(pool, slots, interpret=(impl == "pallas_interpret"))

@@ -101,13 +101,19 @@ def range_pallas(
     limit: int,
     max_leaves: int = 4,
     block_requests: int = 64,
-    interpret: bool = True,
+    interpret: bool,
 ) -> Tuple[jnp.ndarray, ...]:
     """Returns (keys_hi (B,L), keys_lo, vals_hi, vals_lo, n (B,),
     visited_leaves (B, max_leaves), next_leaf (B,)).  ``next_leaf`` is the
     first unwalked leaf (-1 when the chain ended inside the window) — the
     epilogue combines it with the merged count to derive the ``truncated``
-    flag and resume cursor."""
+    flag and resume cursor.
+
+    Not on the served path, and it does not lower for TPU yet: a 64-row
+    tile breaks Mosaic's rule that a rank-1 block is a multiple of 128, and
+    at 128 rows the per-lane scalar stores are refused
+    (``tests/test_tpu_compile.py`` pins both).  The served RANGE is
+    ``core.lookup.range_batch_loop``."""
     B = khi.shape[0]
     assert B % block_requests == 0
     assert limit >= 1, "0-width output blocks break the kernel; ops.range_scan guards limit=0"

@@ -33,12 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU-specific memory spaces; interpret mode runs without a TPU
-    from jax.experimental.pallas import tpu as pltpu
-
-    ANY = pltpu.ANY
-except Exception:  # pragma: no cover - CPU-only container always has this
-    ANY = pl.ANY if hasattr(pl, "ANY") else None
+ANY = pl.ANY  # compiler-placed memory (HBM at real sizes)
 
 
 def _limb_le(a_hi, a_lo, b_hi, b_lo):
@@ -196,12 +191,16 @@ def get_pallas(
     eps_inner: int,
     eps_leaf: int,
     block_requests: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """pallas_call wrapper over the GET kernel.  Returns (vhi, vlo, found).
 
-    ``interpret=True`` executes the kernel body on CPU (this container);
-    on a real TPU pass ``interpret=False``.
+    Not on the served path, and it does not lower for TPU yet: Mosaic
+    refuses its 3-D gathers ("Only 2D gather is supported";
+    ``tests/test_tpu_compile.py`` pins it), and its whole-pool VMEM blocks
+    would not fit v5e's VMEM at 50M keys (``pivot_keys`` alone is ~200 MB).
+    ``interpret=True`` runs the body in the Pallas interpreter, which is how
+    the equivalence tests use it; the served GET is ``core.lookup.get_batch``.
     """
     B = khi.shape[0]
     assert B % block_requests == 0, "pad the wave to the request tile"

@@ -1,8 +1,14 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import: jax locks the device
-# count at first initialisation.  This module is the ONLY place the 512
-# placeholder host devices exist — tests and benches see the real device.
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+_HOSTS = "--xla_force_host_platform_device_count=512"
+if _HOSTS not in os.environ.get("XLA_FLAGS", ""):
+    os.environ["XLA_FLAGS"] = f"{os.environ.get('XLA_FLAGS', '')} {_HOSTS}".strip()
+# The lines above MUST run before any other import: jax locks the platform
+# and device count at first initialisation.  This module is the ONLY place
+# the 512 placeholder host devices exist — tests and benches see the real
+# device.  The dry run always compiles on the CPU: on a machine with a chip,
+# a second process must not claim it.
 
 """Multi-pod dry-run: AOT-lower + compile every (arch x shape x mesh) cell.
 

@@ -1,6 +1,11 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# Must precede every other import (jax locks device count at first init).
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+_HOSTS = "--xla_force_host_platform_device_count=512"
+if _HOSTS not in os.environ.get("XLA_FLAGS", ""):
+    os.environ["XLA_FLAGS"] = f"{os.environ.get('XLA_FLAGS', '')} {_HOSTS}".strip()
+# Must precede every other import (jax locks platform and device count at
+# first init); CPU only, like launch/dryrun.py.
 
 """Multi-pod dry-run of the DPA-Store service itself.
 

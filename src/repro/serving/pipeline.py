@@ -66,6 +66,8 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, List, Optional, Tuple
 
+import jax
+import jax.profiler
 import numpy as np
 
 
@@ -175,13 +177,8 @@ class WaveTicket:
 
 
 def _trace_annotation(label: str):
-    """jax.profiler span around a pipeline phase (no-op if unavailable)."""
-    try:
-        import jax.profiler
-
-        return jax.profiler.TraceAnnotation(label)
-    except Exception:  # pragma: no cover - profiler always ships with jax
-        return contextlib.nullcontext()
+    """jax.profiler span around a pipeline phase."""
+    return jax.profiler.TraceAnnotation(label)
 
 
 class WavePipeline:
@@ -261,23 +258,12 @@ class WavePipeline:
     @contextlib.contextmanager
     def trace(self, log_dir: str):
         """Capture a ``jax.profiler`` trace of everything run inside the
-        context (wave annotations included).  Degrades to a no-op when the
-        profiler backend is unavailable."""
-        started = False
-        try:
-            import jax.profiler
-
-            jax.profiler.start_trace(log_dir)
-            started = True
-        except Exception:
-            pass
+        context (wave annotations included)."""
+        jax.profiler.start_trace(log_dir)
         try:
             yield self
         finally:
-            if started:
-                import jax.profiler
-
-                jax.profiler.stop_trace()
+            jax.profiler.stop_trace()
 
 
 # ---------------------------------------------------------------------------

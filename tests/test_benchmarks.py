@@ -102,7 +102,7 @@ def test_fig16_smoke_rows_cover_shards_and_scan_lengths():
             assert cont[name]["range_reissues"] == 0, (name, cont[name])
     # the real-mesh subprocess leg rides the same run() (its rows carry
     # measured_mops/devices instead of depth/fanout)
-    assert any(r.startswith("fig16/mesh/") for r in rows), "mesh leg emitted no rows"
+    assert any(r.startswith("fig16/mesh_cpu/") for r in rows), "mesh leg emitted no rows"
     model, depth = {}, {}
     for row in rows:
         name, _, derived = row.split(",", 2)
@@ -384,7 +384,7 @@ def test_fig16_mesh_leg_runs_on_forced_devices():
     assert rows, "mesh leg emitted no rows"
     for row in rows:
         name, _, derived = row.split(",", 2)
-        assert name.startswith("fig16/mesh/shards4/"), name
+        assert name.startswith("fig16/mesh_cpu/shards4/"), name
         fields = derived_fields(derived)
         assert int(fields["devices"]) >= 4
         assert float(fields["measured_mops"]) > 0

@@ -259,7 +259,8 @@ def test_generic_probe_covers_both_payload_instantiations():
     ph, pl2 = jnp.asarray(pl_[:, 0]), jnp.asarray(pl_[:, 1])
     # value instantiation (P=2) == hotcache oracle
     h1, vh, vl = cache_probe.probe_pallas(
-        vcache, hotcache.steer(ph, pl2, cfg_v.n_threads), ph, pl2, cfg=cfg_v
+        vcache, hotcache.steer(ph, pl2, cfg_v.n_threads), ph, pl2, cfg=cfg_v,
+        interpret=True,
     )
     h2, vh2, vl2 = ref.cache_probe(
         vcache, hotcache.steer(ph, pl2, cfg_v.n_threads), ph, pl2, cfg=cfg_v
@@ -273,7 +274,8 @@ def test_generic_probe_covers_both_payload_instantiations():
     )
     # leaf-id instantiation (P=1) == scancache oracle
     a1, l1 = cache_probe.anchor_probe_pallas(
-        acache, hotcache.steer(ph, pl2, cfg_a.n_threads), ph, pl2, cfg=cfg_a
+        acache, hotcache.steer(ph, pl2, cfg_a.n_threads), ph, pl2, cfg=cfg_a,
+        interpret=True,
     )
     a2, l2 = ref.scan_anchor_probe(
         acache, hotcache.steer(ph, pl2, cfg_a.n_threads), ph, pl2, cfg=cfg_a
@@ -293,6 +295,7 @@ def test_generic_probe_covers_both_payload_instantiations():
         hotcache.steer(ph, pl2, cfg_a.n_threads), ph, pl2,
         bloom_bits=cfg_a.bloom_bits, n_buckets=cfg_a.n_buckets,
         salts_bloom=scancache.SALT_SBLOOM, salt_bucket=scancache.SALT_SBUCKET,
+        interpret=True,
     )
     np.testing.assert_array_equal(np.asarray(h3), np.asarray(a2))
     m = np.asarray(a2)
