@@ -24,6 +24,7 @@ hacks.
 
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
@@ -33,6 +34,7 @@ import jax.numpy as jnp
 from . import api, hotcache, insert_buffer, lookup, patch, scancache, stitch
 from .api import RangeResult
 from .epoch import EpochManager, EpochRetiredError
+from .ledger import span
 from .ttl import TTLTracker
 from .hotcache import CacheConfig, CacheState
 from .keys import KEY_MAX, join_u64, limb_hash_np, split_u64
@@ -110,11 +112,12 @@ class StoreStats:
     # store through extract_slice / ingest_slice
     migrated_out_keys: int = 0
     migrated_in_keys: int = 0
-    # wave-pipeline timing ledger (serving.pipeline.PipelinedStore folds the
-    # measured per-wave issue/drain nanoseconds back in here so perfmodel
-    # roofline comparisons can read them next to the byte/patch counters)
-    wave_issue_ns: int = 0
-    wave_drain_ns: int = 0
+    # host time of maintenance (the ``flush``, ``plan`` and ``stitch``
+    # spans): flush cycles that drain insert buffers, patch planning, and
+    # COPY + CONNECT + epoch end; plan and stitch lie inside flush
+    flush_ns: int = 0
+    plan_ns: int = 0
+    stitch_ns: int = 0
 
 
 @dataclass
@@ -302,7 +305,8 @@ class DPAStore:
         self.scan_cache, n = scancache.invalidate_leaves(
             self.scan_cache, jnp.asarray(padded)
         )
-        self.stats.scan_invalidated += int(n)
+        with span("wait.invalidate", waits=1):
+            self.stats.scan_invalidated += int(n)
 
     # ------------------------------------------- point-in-time read window
     def snapshot_epoch(self) -> int:
@@ -403,20 +407,22 @@ class DPAStore:
             )
         keys_u64 = np.asarray(keys, dtype=np.uint64)
         n = keys_u64.size
-        B = _pad_pow2(n)
-        khi, klo, active = self._limbs(keys_u64, B)
+        with span("build"):
+            B = _pad_pow2(n)
+            khi, klo, active = self._limbs(keys_u64, B)
         if as_of is not None:
             e = self.epochs.check_retained(as_of)
-            res_table = self._resolve_table(e)
-            vhi, vlo, found = lookup.get_batch_versioned(
-                self.tree,
-                res_table,
-                khi,
-                klo,
-                depth=self.depth,
-                eps_inner=self.cfg.eps_inner,
-                eps_leaf=self.cfg.eps_leaf,
-            )
+            with span("launch"):
+                res_table = self._resolve_table(e)
+                vhi, vlo, found = lookup.get_batch_versioned(
+                    self.tree,
+                    res_table,
+                    khi,
+                    klo,
+                    depth=self.depth,
+                    eps_inner=self.cfg.eps_inner,
+                    eps_leaf=self.cfg.eps_leaf,
+                )
             snap = self._ttl_snap_for(e)
             expired = (
                 TTLTracker.expired_at(snap, keys_u64)
@@ -429,41 +435,42 @@ class DPAStore:
                 n=n, vhi=vhi, vlo=vlo, found=found, hits=None, expired=expired
             )
         use_cache = self.cache is not None
-        if use_cache:
-            tid = self._steer(khi, klo)
-            c_hit, c_vhi, c_vlo = hotcache.probe(
-                self.cache, tid, khi, klo, cfg=self.cache_cfg
-            )
-        vhi, vlo, found = lookup.get_batch(
-            self.tree,
-            self.ib,
-            khi,
-            klo,
-            depth=self.depth,
-            eps_inner=self.cfg.eps_inner,
-            eps_leaf=self.cfg.eps_leaf,
-        )
-        hits = None
-        if use_cache:
-            out_vhi = jnp.where(c_hit, c_vhi, vhi)
-            out_vlo = jnp.where(c_hit, c_vlo, vlo)
-            out_found = c_hit | found
-            eligible = found & ~c_hit & active
-            self.cache = hotcache.admit(
-                self.cache,
-                tid,
+        with span("launch"):
+            if use_cache:
+                tid = self._steer(khi, klo)
+                c_hit, c_vhi, c_vlo = hotcache.probe(
+                    self.cache, tid, khi, klo, cfg=self.cache_cfg
+                )
+            vhi, vlo, found = lookup.get_batch(
+                self.tree,
+                self.ib,
                 khi,
                 klo,
-                vhi,
-                vlo,
-                eligible,
-                cfg=self.cache_cfg,
-                wave=self.stats.waves & 0xFFFFFFFF,
+                depth=self.depth,
+                eps_inner=self.cfg.eps_inner,
+                eps_leaf=self.cfg.eps_leaf,
             )
-            hits = c_hit & active
-            self.stats.cache_probes += n
-        else:
-            out_vhi, out_vlo, out_found = vhi, vlo, found
+            hits = None
+            if use_cache:
+                out_vhi = jnp.where(c_hit, c_vhi, vhi)
+                out_vlo = jnp.where(c_hit, c_vlo, vlo)
+                out_found = c_hit | found
+                eligible = found & ~c_hit & active
+                self.cache = hotcache.admit(
+                    self.cache,
+                    tid,
+                    khi,
+                    klo,
+                    vhi,
+                    vlo,
+                    eligible,
+                    cfg=self.cache_cfg,
+                    wave=self.stats.waves & 0xFFFFFFFF,
+                )
+                hits = c_hit & active
+                self.stats.cache_probes += n
+            else:
+                out_vhi, out_vlo, out_found = vhi, vlo, found
         self.stats.gets += n
         expired = self.ttl.is_expired_np(keys_u64) if self.ttl else None
         self._end_wave()
@@ -475,19 +482,22 @@ class DPAStore:
     def get_finalize(self, w: _GetWave) -> Tuple[np.ndarray, np.ndarray]:
         """Drain half of GET: blocking gather + host epilogue."""
         if w.hits is not None:
-            self.stats.cache_hits += int(jnp.sum(w.hits))
+            with span("wait.stats", waits=1):
+                self.stats.cache_hits += int(jnp.sum(w.hits))
         n = w.n
-        vals = join_u64(
-            np.stack([np.asarray(w.vhi)[:n], np.asarray(w.vlo)[:n]], axis=-1)
-        )
-        found = np.asarray(w.found)[:n]
-        if w.expired is not None:
-            # TTL: a key past its deadline reads as absent (the sweep will
-            # physically delete it later; filter-vs-reclaim equivalence)
-            found = found & ~w.expired
-        # protocol contract: not-found rows carry 0, never slot residue —
-        # so responses are bitwise identical no matter which tier serves them
-        vals[~found] = 0
+        with span("wait.results", waits=3):
+            vhi = np.asarray(w.vhi)[:n]
+            vlo = np.asarray(w.vlo)[:n]
+            found = np.asarray(w.found)[:n]
+        with span("epilogue"):
+            vals = join_u64(np.stack([vhi, vlo], axis=-1))
+            if w.expired is not None:
+                # TTL: a key past its deadline reads as absent (the sweep will
+                # physically delete it later; filter-vs-reclaim equivalence)
+                found = found & ~w.expired
+            # protocol contract: not-found rows carry 0, never slot residue —
+            # so responses are bitwise identical no matter which tier serves
+            vals[~found] = 0
         return vals, found
 
     # ---------------------------------------------------------------- writes
@@ -508,51 +518,58 @@ class DPAStore:
         first = True
         stalled = 0
         while pending.size and (auto_retry or first):
-            first = False
-            st = self._write_wave(keys_u64[pending], vals_u64[pending], op_code)
-            statuses[pending] = st
-            self._process_full_leaves()
-            next_pending = pending[st == STATUS_RETRY]
-            if next_pending.size == pending.size:
-                # no lane landed: drain the responsible buffers so the
-                # re-send can succeed (paper: client re-sends after timeout,
-                # by which time the patch cycle has emptied the buffer)
-                stalled += 1
-                self._flush_leaves_of(keys_u64[next_pending])
-                if stalled >= 3:  # defensive; cannot happen after a flush
-                    break
-            else:
-                stalled = 0
-            if next_pending.size:
-                self.stats.retries += next_pending.size
-            pending = next_pending
+            # every round after the first re-sends lanes a full buffer refused
+            with contextlib.nullcontext() if first else span("retry"):
+                first = False
+                st = self._write_wave(
+                    keys_u64[pending], vals_u64[pending], op_code
+                )
+                statuses[pending] = st
+                self._process_full_leaves()
+                next_pending = pending[st == STATUS_RETRY]
+                if next_pending.size == pending.size:
+                    # no lane landed: drain the responsible buffers so the
+                    # re-send can succeed (paper: client re-sends after
+                    # timeout, by which time the patch cycle has emptied it)
+                    stalled += 1
+                    self._flush_leaves_of(keys_u64[next_pending])
+                    if stalled >= 3:  # defensive; cannot happen after a flush
+                        break
+                else:
+                    stalled = 0
+                if next_pending.size:
+                    self.stats.retries += next_pending.size
+                pending = next_pending
         return statuses
 
     def _write_wave(self, keys_u64, vals_u64, op_code: int) -> np.ndarray:
         n = keys_u64.size
-        B = _pad_pow2(n)
-        khi, klo, active = self._limbs(keys_u64, B)
-        vv = np.zeros(B, dtype=np.uint64)
-        vv[:n] = vals_u64
-        vlimbs = split_u64(vv)
-        vhi = jnp.asarray(vlimbs[:, 0])
-        vlo = jnp.asarray(vlimbs[:, 1])
-        leaf = lookup.traverse(
-            self.tree, khi, klo, depth=self.depth, eps_inner=self.cfg.eps_inner
-        )
-        op = jnp.full(B, op_code, dtype=jnp.int32)
-        self.ib, status = insert_buffer.append_wave(
-            self.ib, leaf, khi, klo, vhi, vlo, op, active
-        )
-        if self.cache is not None:
-            # UPDATE/DELETE invalidate cached entries (paper Sec 3.1.2)
-            tid = self._steer(khi, klo)
-            self.cache = hotcache.invalidate(
-                self.cache, tid, khi, klo, active, cfg=self.cache_cfg
+        with span("build"):
+            B = _pad_pow2(n)
+            khi, klo, active = self._limbs(keys_u64, B)
+            vv = np.zeros(B, dtype=np.uint64)
+            vv[:n] = vals_u64
+            vlimbs = split_u64(vv)
+            vhi = jnp.asarray(vlimbs[:, 0])
+            vlo = jnp.asarray(vlimbs[:, 1])
+        with span("launch"):
+            leaf = lookup.traverse(
+                self.tree, khi, klo, depth=self.depth, eps_inner=self.cfg.eps_inner
             )
+            op = jnp.full(B, op_code, dtype=jnp.int32)
+            self.ib, status = insert_buffer.append_wave(
+                self.ib, leaf, khi, klo, vhi, vlo, op, active
+            )
+            if self.cache is not None:
+                # UPDATE/DELETE invalidate cached entries (paper Sec 3.1.2)
+                tid = self._steer(khi, klo)
+                self.cache = hotcache.invalidate(
+                    self.cache, tid, khi, klo, active, cfg=self.cache_cfg
+                )
         self._ib_shadow = None  # serial append: shadow prediction is stale
         self._end_wave()
-        return np.asarray(status)[:n]
+        with span("wait.results", waits=1):
+            return np.asarray(status)[:n]
 
     # ------------------------------------------- async write fast path
     def _write_plan(self, keys_u64: np.ndarray):
@@ -570,7 +587,8 @@ class DPAStore:
             # blocks only if an in-flight wave donated ib — the pipelined
             # facade never lets that happen on this path (reads don't touch
             # ib; prior fast-path writes kept the shadow live)
-            self._ib_shadow = np.asarray(self.ib.count).copy()
+            with span("wait.shadow", waits=1):
+                self._ib_shadow = np.asarray(self.ib.count).copy()
         leaves = np.fromiter(
             (self.image.find_leaf(k)[0] for k in keys_u64),
             dtype=np.int64,
@@ -597,35 +615,37 @@ class DPAStore:
         n = keys_u64.size
         if n == 0:
             return _WriteWave(n=0, status=np.zeros(0, dtype=np.int32))
-        adds = self._write_plan(keys_u64)
-        if adds is None:
-            return None
-        vals_u64 = (
-            np.zeros_like(keys_u64)
-            if vals is None
-            else np.asarray(vals, dtype=np.uint64)
-        )
-        op_code = IB_PUT if op == "put" else IB_DEL
-        B = _pad_pow2(n)
-        khi, klo, active = self._limbs(keys_u64, B)
-        vv = np.zeros(B, dtype=np.uint64)
-        vv[:n] = vals_u64
-        vlimbs = split_u64(vv)
-        vhi = jnp.asarray(vlimbs[:, 0])
-        vlo = jnp.asarray(vlimbs[:, 1])
-        leaf = lookup.traverse(
-            self.tree, khi, klo, depth=self.depth, eps_inner=self.cfg.eps_inner
-        )
-        opv = jnp.full(B, op_code, dtype=jnp.int32)
-        self.ib, status = insert_buffer.append_wave(
-            self.ib, leaf, khi, klo, vhi, vlo, opv, active
-        )
-        self._ib_shadow += adds  # exact: every lane proven to land
-        if self.cache is not None:
-            tid = self._steer(khi, klo)
-            self.cache = hotcache.invalidate(
-                self.cache, tid, khi, klo, active, cfg=self.cache_cfg
+        with span("build"):
+            adds = self._write_plan(keys_u64)
+            if adds is None:
+                return None
+            vals_u64 = (
+                np.zeros_like(keys_u64)
+                if vals is None
+                else np.asarray(vals, dtype=np.uint64)
             )
+            op_code = IB_PUT if op == "put" else IB_DEL
+            B = _pad_pow2(n)
+            khi, klo, active = self._limbs(keys_u64, B)
+            vv = np.zeros(B, dtype=np.uint64)
+            vv[:n] = vals_u64
+            vlimbs = split_u64(vv)
+            vhi = jnp.asarray(vlimbs[:, 0])
+            vlo = jnp.asarray(vlimbs[:, 1])
+        with span("launch"):
+            leaf = lookup.traverse(
+                self.tree, khi, klo, depth=self.depth, eps_inner=self.cfg.eps_inner
+            )
+            opv = jnp.full(B, op_code, dtype=jnp.int32)
+            self.ib, status = insert_buffer.append_wave(
+                self.ib, leaf, khi, klo, vhi, vlo, opv, active
+            )
+            self._ib_shadow += adds  # exact: every lane proven to land
+            if self.cache is not None:
+                tid = self._steer(khi, klo)
+                self.cache = hotcache.invalidate(
+                    self.cache, tid, khi, klo, active, cfg=self.cache_cfg
+                )
         self._end_wave()
         if op == "put":
             self.stats.puts += n
@@ -642,7 +662,8 @@ class DPAStore:
         the issue-time proof, but the device array is authoritative)."""
         if w.n == 0:
             return np.asarray(w.status)
-        return np.asarray(w.status)[: w.n]
+        with span("wait.results", waits=1):
+            return np.asarray(w.status)[: w.n]
 
     def put(
         self,
@@ -753,44 +774,51 @@ class DPAStore:
         The traversal device call is skipped entirely when no lane needs it
         — the anchor cache's descent-skip fast path."""
         B = int(khi.shape[0])
-        start = jnp.asarray(resume_np)  # -1 = fresh descent wanted
         fresh_np = np.zeros(B, dtype=bool)
         fresh_np[:n_active] = resume_np[:n_active] < 0
         hit_np = np.zeros(B, dtype=bool)
         tid = None
-        if self.scan_cache is not None and fresh_np.any():
-            # steer with the SCAN cache's thread geometry (the point cache
-            # may be differently sized or disabled entirely)
-            tid = hotcache.steer(khi, klo, self.scan_cache_cfg.n_threads)
-            hit, cleaf = scancache.probe(
-                self.scan_cache, tid, khi, klo, cfg=self.scan_cache_cfg
-            )
-            hit_np = np.asarray(hit) & fresh_np
+        probe = self.scan_cache is not None and fresh_np.any()
+        with span("launch"):
+            start = jnp.asarray(resume_np)  # -1 = fresh descent wanted
+            if probe:
+                # steer with the SCAN cache's thread geometry (the point
+                # cache may be differently sized or disabled entirely)
+                tid = hotcache.steer(khi, klo, self.scan_cache_cfg.n_threads)
+                hit, cleaf = scancache.probe(
+                    self.scan_cache, tid, khi, klo, cfg=self.scan_cache_cfg
+                )
+        if probe:
+            with span("wait.scan_probe", waits=1):
+                hit_np = np.asarray(hit) & fresh_np
             self.stats.scan_probes += int(fresh_np.sum())
             self.stats.scan_hits += int(hit_np.sum())
-            start = jnp.where((start < 0) & jnp.asarray(hit_np), cleaf, start)
-        need_traverse = fresh_np & ~hit_np
-        tstart = None
-        if need_traverse.any():
-            tstart = lookup.traverse(
-                self.tree, khi, klo, depth=self.depth, eps_inner=self.cfg.eps_inner
-            )
-            start = jnp.where(start < 0, tstart, start)
-        if self.scan_cache is not None and tstart is not None:
-            # admit the fresh descents the cache missed (anchor = the leaf
-            # the descent bottomed out at; exact-key entries, so a later
-            # RANGE with the same k_min skips the whole descent)
-            self.scan_cache = scancache.admit(
-                self.scan_cache,
-                tid,
-                khi,
-                klo,
-                tstart,
-                jnp.asarray(need_traverse),
-                cfg=self.scan_cache_cfg,
-                wave=self.stats.waves & 0xFFFFFFFF,
-                epoch=self.stats.flush_cycles,
-            )
+        with span("launch"):
+            if probe:
+                start = jnp.where((start < 0) & jnp.asarray(hit_np), cleaf, start)
+            need_traverse = fresh_np & ~hit_np
+            tstart = None
+            if need_traverse.any():
+                tstart = lookup.traverse(
+                    self.tree, khi, klo, depth=self.depth,
+                    eps_inner=self.cfg.eps_inner,
+                )
+                start = jnp.where(start < 0, tstart, start)
+            if self.scan_cache is not None and tstart is not None:
+                # admit the fresh descents the cache missed (anchor = the leaf
+                # the descent bottomed out at; exact-key entries, so a later
+                # RANGE with the same k_min skips the whole descent)
+                self.scan_cache = scancache.admit(
+                    self.scan_cache,
+                    tid,
+                    khi,
+                    klo,
+                    tstart,
+                    jnp.asarray(need_traverse),
+                    cfg=self.scan_cache_cfg,
+                    wave=self.stats.waves & 0xFFFFFFFF,
+                    epoch=self.stats.flush_cycles,
+                )
         return start
 
     def range_with_state(
@@ -918,35 +946,56 @@ class DPAStore:
             return w
         if start_leaves is not None:
             self.stats.range_reissue_rounds += 1
-        B = _pad_pow2(n)
-        khi, klo, active = self._limbs(start_keys_u64, B)
-        res_pad = np.full(B, -1, dtype=np.int32)
-        if start_leaves is not None:
-            res_pad[:n] = np.asarray(start_leaves, dtype=np.int32)
-        ubs = np.full(B, KEY_MAX, dtype=np.uint64)  # sentinel: no clip
-        if k_max is not None:
-            ubs[:n] = np.asarray(k_max, dtype=np.uint64)
-        ub_limbs = split_u64(ubs)
+        with span("build"):
+            B = _pad_pow2(n)
+            khi, klo, active = self._limbs(start_keys_u64, B)
+            res_pad = np.full(B, -1, dtype=np.int32)
+            if start_leaves is not None:
+                res_pad[:n] = np.asarray(start_leaves, dtype=np.int32)
+            ubs = np.full(B, KEY_MAX, dtype=np.uint64)  # sentinel: no clip
+            if k_max is not None:
+                ubs[:n] = np.asarray(k_max, dtype=np.uint64)
+            ub_limbs = split_u64(ubs)
         if as_of is not None:
             # versioned walk: plain descent for fresh rows (the scan-anchor
             # cache serves LIVE pagination; versioned reads must not churn
             # its admissions), resolve table gathered per walked leaf
             w.as_of = as_of
-            start = jnp.asarray(res_pad)
-            if (res_pad[:n] < 0).any():
-                tstart = lookup.traverse(
-                    self.tree,
-                    khi,
-                    klo,
-                    depth=self.depth,
-                    eps_inner=self.cfg.eps_inner,
+            with span("launch"):
+                start = jnp.asarray(res_pad)
+                if (res_pad[:n] < 0).any():
+                    tstart = lookup.traverse(
+                        self.tree,
+                        khi,
+                        klo,
+                        depth=self.depth,
+                        eps_inner=self.cfg.eps_inner,
+                    )
+                    start = jnp.where(start < 0, tstart, start)
+                start = jnp.where(active, start, -1)
+                w.rk, w.rv, w.valid, w.trunc, w.cursor, w.rounds = (
+                    lookup.range_batch_loop_versioned(
+                        self.tree,
+                        self._resolve_table(as_of),
+                        start,
+                        khi,
+                        klo,
+                        jnp.asarray(ub_limbs[:, 0]),
+                        jnp.asarray(ub_limbs[:, 1]),
+                        limit=limit,
+                        max_leaves=max_leaves,
+                        max_rounds=0 if max_rounds is None else max_rounds,
+                    )
                 )
-                start = jnp.where(start < 0, tstart, start)
-            start = jnp.where(active, start, -1)
+            self._end_wave()
+            return w
+        start = self._scan_start(khi, klo, res_pad, n)
+        with span("launch"):
+            start = jnp.where(active, start, -1)  # pad rows ride along dead
             w.rk, w.rv, w.valid, w.trunc, w.cursor, w.rounds = (
-                lookup.range_batch_loop_versioned(
+                lookup.range_batch_loop(
                     self.tree,
-                    self._resolve_table(as_of),
+                    self.ib,
                     start,
                     khi,
                     klo,
@@ -957,24 +1006,6 @@ class DPAStore:
                     max_rounds=0 if max_rounds is None else max_rounds,
                 )
             )
-            self._end_wave()
-            return w
-        start = self._scan_start(khi, klo, res_pad, n)
-        start = jnp.where(active, start, -1)  # pad rows ride along dead
-        w.rk, w.rv, w.valid, w.trunc, w.cursor, w.rounds = (
-            lookup.range_batch_loop(
-                self.tree,
-                self.ib,
-                start,
-                khi,
-                klo,
-                jnp.asarray(ub_limbs[:, 0]),
-                jnp.asarray(ub_limbs[:, 1]),
-                limit=limit,
-                max_leaves=max_leaves,
-                max_rounds=0 if max_rounds is None else max_rounds,
-            )
-        )
         self._end_wave()
         return w
 
@@ -994,33 +1025,42 @@ class DPAStore:
                 cursor_key=cur_key_out, rounds=w.rounds_done,
                 stats=w.stats_out or {}, _arity=w.arity,
             )
-        self.stats.range_rounds_in_mesh += max(int(w.rounds) - 1, 0)
-        va = np.asarray(w.valid)[:n]
-        rc = va.sum(axis=1)
-        keys_np = join_u64(np.asarray(w.rk)[:n])
-        vals_np = join_u64(np.asarray(w.rv)[:n])
-        keys_out[:] = np.where(va, keys_np, 0)
-        vals_out[:] = np.where(va, vals_np, 0)
-        counts[:] = rc
-        trunc_out[:] = np.asarray(w.trunc)[:n]
-        cur_leaf_out[:] = np.asarray(w.cursor.leaf)[:n]
-        last_key = join_u64(
-            np.stack(
-                [np.asarray(w.cursor.khi)[:n], np.asarray(w.cursor.klo)[:n]],
-                axis=-1,
-            )
-        )
-        emitted = rc > 0
-        cur_key_out[emitted] = last_key[emitted]
-        trunc_out &= counts < limit
-        self.stats.range_truncated += int(trunc_out.sum())
-        if not w.resumed and w.as_of is None:
-            # only fresh client-entry scans admit their cursors: a resumed
-            # call (start_leaves given) is an orchestration round — the
-            # sharded facade re-issues those itself, so its interior
-            # cursors would never be probed and would only evict real
-            # pagination anchors (and cost a host descent each)
-            self._admit_cursor_anchors(trunc_out, cur_key_out)
+        with span("wait.results", waits=8):
+            rounds = int(w.rounds)
+            va = np.asarray(w.valid)[:n]
+            rk = np.asarray(w.rk)[:n]
+            rv = np.asarray(w.rv)[:n]
+            trunc = np.asarray(w.trunc)[:n]
+            cleaf = np.asarray(w.cursor.leaf)[:n]
+            ckhi = np.asarray(w.cursor.khi)[:n]
+            cklo = np.asarray(w.cursor.klo)[:n]
+        eligible = None
+        with span("epilogue"):
+            self.stats.range_rounds_in_mesh += max(rounds - 1, 0)
+            rc = va.sum(axis=1)
+            keys_out[:] = np.where(va, join_u64(rk), 0)
+            vals_out[:] = np.where(va, join_u64(rv), 0)
+            counts[:] = rc
+            trunc_out[:] = trunc
+            cur_leaf_out[:] = cleaf
+            last_key = join_u64(np.stack([ckhi, cklo], axis=-1))
+            emitted = rc > 0
+            cur_key_out[emitted] = last_key[emitted]
+            trunc_out &= counts < limit
+            self.stats.range_truncated += int(trunc_out.sum())
+            if not w.resumed and w.as_of is None:
+                # only fresh client-entry scans admit their cursors: a
+                # resumed call (start_leaves given) is an orchestration
+                # round — the sharded facade re-issues those itself, so its
+                # interior cursors would never be probed and would only
+                # evict real pagination anchors (and cost a host descent)
+                eligible = self._admit_cursor_anchors(trunc_out, cur_key_out)
+        if eligible is not None:
+            with span("wait.stats", waits=1):
+                self.stats.scan_cursor_admits += int(np.asarray(eligible).sum())
+        stats = {"rounds_in_mesh": max(rounds - 1, 0), "reissue": int(w.resumed)}
+        if w.as_of is not None:
+            stats["as_of"] = int(w.as_of)
         return RangeResult(
             keys=keys_out,
             vals=vals_out,
@@ -1028,19 +1068,8 @@ class DPAStore:
             truncated=trunc_out,
             cursor_leaf=cur_leaf_out,
             cursor_key=cur_key_out,
-            rounds=int(w.rounds),
-            stats=(
-                {
-                    "rounds_in_mesh": max(int(w.rounds) - 1, 0),
-                    "reissue": int(w.resumed),
-                }
-                if w.as_of is None
-                else {
-                    "rounds_in_mesh": max(int(w.rounds) - 1, 0),
-                    "reissue": int(w.resumed),
-                    "as_of": int(w.as_of),
-                }
-            ),
+            rounds=rounds,
+            stats=stats,
             _arity=w.arity,
         )
 
@@ -1056,16 +1085,19 @@ class DPAStore:
         descent.  The admitted entry is bit-identical to what a later
         miss-then-traverse would admit, so the cache's existing safety
         arguments — buffered writes visible through the walk, restitch
-        invalidation by leaf id — apply unchanged."""
+        invalidation by leaf id — apply unchanged.
+
+        Returns the device mask of admitted lanes (None when nothing was
+        probed); the caller counts it into ``scan_cursor_admits``."""
         if self.scan_cache is None or not self.scan_cache_cfg.admit_cursors:
-            return
+            return None
         m = np.where(trunc)[0]
         if m.size == 0:
-            return
+            return None
         nxt = last_keys[m] + np.uint64(1)
         nxt = nxt[nxt < KEY_MAX]  # 2^64-1 is the reserved sentinel
         if nxt.size == 0:
-            return
+            return None
         leaves = np.array(
             [self.image.find_leaf(k)[0] for k in nxt], dtype=np.int32
         )
@@ -1089,7 +1121,7 @@ class DPAStore:
             wave=self.stats.waves & 0xFFFFFFFF,
             epoch=self.stats.flush_cycles,
         )
-        self.stats.scan_cursor_admits += int(np.asarray(eligible).sum())
+        return eligible
 
     def _range_filtered(
         self,
@@ -1179,13 +1211,15 @@ class DPAStore:
 
     # ------------------------------------------------------------ patch path
     def _process_full_leaves(self) -> int:
-        counts = np.asarray(self.ib.count)
+        with span("wait.counts", waits=1):
+            counts = np.asarray(self.ib.count)
         full = np.where(counts >= self.cfg.ib_cap)[0]
         return self._patch_cycle([int(l) for l in full])
 
     def _flush_leaves_of(self, keys_u64: np.ndarray) -> None:
         """Patch the (non-empty) buffers responsible for RETRYing keys."""
-        counts = np.asarray(self.ib.count)
+        with span("wait.counts", waits=1):
+            counts = np.asarray(self.ib.count)
         leaves = []
         for k in np.asarray(keys_u64, dtype=np.uint64):
             leaf, _ = self.image.find_leaf(k)
@@ -1195,17 +1229,19 @@ class DPAStore:
 
     def flush(self) -> int:
         """Patch every non-empty insert buffer as one flush cycle."""
-        counts = np.asarray(self.ib.count)
+        with span("wait.counts", waits=1):
+            counts = np.asarray(self.ib.count)
         leaves = np.where(counts > 0)[0]
         return self._patch_cycle([int(l) for l in leaves])
 
     def _buffer_entries(self, leaves):
         """Snapshot the buffered ops of the given leaves (host-side read of
         the staged writes — the 'migrate to host' half of the cycle)."""
-        counts = np.asarray(self.ib.count)
-        ib_keys = np.asarray(self.ib.keys)
-        ib_vals = np.asarray(self.ib.vals)
-        ib_ops = np.asarray(self.ib.op)
+        with span("wait.buffers", waits=4):
+            counts = np.asarray(self.ib.count)
+            ib_keys = np.asarray(self.ib.keys)
+            ib_vals = np.asarray(self.ib.vals)
+            ib_ops = np.asarray(self.ib.op)
         out = []
         for leaf in leaves:
             cnt = int(counts[leaf])
@@ -1245,12 +1281,20 @@ class DPAStore:
         Only when pool headroom runs out mid-plan does the cycle split into
         multiple transactions (degrading toward the per-leaf cadence, whose
         interleaved reclaim keeps the store live).  Falls back to the
-        per-leaf oracle stream when ``batched_patch`` is off."""
-        counts = np.asarray(self.ib.count)
+        per-leaf oracle stream when ``batched_patch`` is off.  Its host
+        time, buffer snapshot included, is the ``flush`` span and
+        ``stats.flush_ns``."""
+        with span("wait.counts", waits=1):
+            counts = np.asarray(self.ib.count)
         leaves = [int(l) for l in leaves if int(counts[int(l)]) > 0]
         if not leaves:
             return 0
-        return self._run_patch_cycle(list(zip(leaves, self._buffer_entries(leaves))))
+        with span("flush") as s:
+            n = self._run_patch_cycle(
+                list(zip(leaves, self._buffer_entries(leaves)))
+            )
+        self.stats.flush_ns += s.ns
+        return n
 
     def _run_patch_cycle(self, pending) -> int:
         """One flush cycle over explicit ``(leaf, entries)`` work items.
@@ -1269,31 +1313,37 @@ class DPAStore:
             # version-chain stamp: leaves this transaction emits are born at
             # the cycle it completes as (end_cycle increments afterwards)
             self.image.version_cycle = self.epochs.cycle + 1
-            result = patch.plan_patch_batch(
-                self.image, chunk_leaves, chunk_entries,
-                headroom_ok=self._headroom_ok,
-                force_structural=self.retain_epochs > 0,
-            )
+            with span("plan") as s:
+                result = patch.plan_patch_batch(
+                    self.image, chunk_leaves, chunk_entries,
+                    headroom_ok=self._headroom_ok,
+                    force_structural=self.retain_epochs > 0,
+                )
+            self.stats.plan_ns += s.ns
             pending = result.unplanned
-            # COPY then CONNECT — the stitch atomicity contract, once per
-            # transaction (one per cycle unless headroom forced a split)
-            self.tree = stitch.apply_copies(self.tree, result.batch)
-            self.tree, self.ib = stitch.apply_connects(
-                self.tree, self.ib, result.batch
-            )
-            self._ib_shadow = None  # connects drained buffers: shadow stale
-            self.stats.stitch_applies += 1
-            # Cycle-granularity epoch bookkeeping: quarantine everything the
-            # transaction obsoleted, advance once.  (Within the transaction
-            # nothing was reclaimed, so no COPY could have landed on a
-            # still-reachable row.)  The on_defer listener collects the
-            # cycle's obsoleted leaves; dropping their scan anchors here —
-            # before the cycle returns — is what keeps a restitched leaf
-            # chain from ever serving a cached-anchor scan.
-            self.epochs.defer_free_batch(result.batch.frees)
-            self._apply_scan_invalidation()
-            self.stats.reclaimed += self.epochs.end_cycle(self.image)
-            self._note_cycle_end()
+            with span("stitch") as s:
+                # COPY then CONNECT — the stitch atomicity contract, once
+                # per transaction (one per cycle unless headroom forced a
+                # split)
+                self.tree = stitch.apply_copies(self.tree, result.batch)
+                self.tree, self.ib = stitch.apply_connects(
+                    self.tree, self.ib, result.batch
+                )
+                self._ib_shadow = None  # connects drained buffers: stale
+                self.stats.stitch_applies += 1
+                # Cycle-granularity epoch bookkeeping: quarantine everything
+                # the transaction obsoleted, advance once.  (Within the
+                # transaction nothing was reclaimed, so no COPY could have
+                # landed on a still-reachable row.)  The on_defer listener
+                # collects the cycle's obsoleted leaves; dropping their scan
+                # anchors here — before the cycle returns — is what keeps a
+                # restitched leaf chain from ever serving a cached-anchor
+                # scan.
+                self.epochs.defer_free_batch(result.batch.frees)
+                self._apply_scan_invalidation()
+                self.stats.reclaimed += self.epochs.end_cycle(self.image)
+                self._note_cycle_end()
+            self.stats.stitch_ns += s.ns
             self.stats.stitched_bytes += result.batch.payload_bytes()
             self.stats.stitched_dpa_bytes += result.batch.dpa_bytes()
             self.stats.patches_update += result.n_update
@@ -1305,33 +1355,41 @@ class DPAStore:
     def _patch_leaf(self, leaf: int) -> None:
         """Per-leaf oracle path: one stitch transaction per patched leaf
         (the pre-batching stream; kept for equivalence testing)."""
-        cnt = int(np.asarray(self.ib.count)[leaf])
+        with span("wait.counts", waits=1):
+            cnt = int(np.asarray(self.ib.count)[leaf])
         if cnt == 0:
             return
         self._patch_leaf_entries(leaf, self._buffer_entries([leaf])[0])
 
     def _patch_leaf_entries(self, leaf: int, entries) -> None:
         self.image.version_cycle = self.epochs.cycle + 1
-        result = patch.plan_patch(
-            self.image, leaf, entries,
-            force_structural=self.retain_epochs > 0,
-        )
-        # COPY then CONNECT — the stitch atomicity contract
-        self.tree = stitch.apply_copies(self.tree, result.batch)
-        self.tree, self.ib = stitch.apply_connects(self.tree, self.ib, result.batch)
-        self._ib_shadow = None  # connects drained buffers: shadow stale
-        self.stats.stitch_applies += 1
-        self.stats.patched_leaves += 1
-        for pool, idx in result.batch.frees:
-            self.epochs.defer_free(pool, idx)
-        self._apply_scan_invalidation()
-        # Patches run with no wave in flight (host-serialized), so every
-        # traverser has trivially "moved on": advancing the epoch here is the
-        # degenerate-but-sound case of the paper's packet-counter epoch.
-        # end_cycle = advance + reclaim, plus the version-cycle increment the
-        # per-leaf stream owes (one transaction per patched leaf).
-        self.stats.reclaimed += self.epochs.end_cycle(self.image)
-        self._note_cycle_end()
+        with span("plan") as s:
+            result = patch.plan_patch(
+                self.image, leaf, entries,
+                force_structural=self.retain_epochs > 0,
+            )
+        self.stats.plan_ns += s.ns
+        with span("stitch") as s:
+            # COPY then CONNECT — the stitch atomicity contract
+            self.tree = stitch.apply_copies(self.tree, result.batch)
+            self.tree, self.ib = stitch.apply_connects(
+                self.tree, self.ib, result.batch
+            )
+            self._ib_shadow = None  # connects drained buffers: shadow stale
+            self.stats.stitch_applies += 1
+            self.stats.patched_leaves += 1
+            for pool, idx in result.batch.frees:
+                self.epochs.defer_free(pool, idx)
+            self._apply_scan_invalidation()
+            # Patches run with no wave in flight (host-serialized), so every
+            # traverser has trivially "moved on": advancing the epoch here
+            # is the degenerate-but-sound case of the paper's packet-counter
+            # epoch.  end_cycle = advance + reclaim, plus the version-cycle
+            # increment the per-leaf stream owes (one transaction per
+            # patched leaf).
+            self.stats.reclaimed += self.epochs.end_cycle(self.image)
+            self._note_cycle_end()
+        self.stats.stitch_ns += s.ns
         self.stats.stitched_bytes += result.batch.payload_bytes()
         self.stats.stitched_dpa_bytes += result.batch.dpa_bytes()
         if result.kind == "update":

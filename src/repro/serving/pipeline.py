@@ -48,14 +48,25 @@ Correctness contract (what makes pipelined == serial bitwise):
   in issue order, so no host code can observe a deleted buffer.
   ``tests/test_pipeline.py`` pins both halves of this contract.
 
-Observability: every wave is timed into a :class:`WaveLedger`
-(``wave_issue_ns`` / ``wave_drain_ns`` per wave plus in-flight intervals);
-``overlap_frac`` measures how much of the pipeline's busy time had >1 wave
-in flight (0 by construction at ``queue_depth=1``).  When ``jax.profiler``
-is available each phase is wrapped in a ``TraceAnnotation`` so device
-traces show the overlap, and :meth:`WavePipeline.trace` captures a full
-profiler trace directory.  ``core.perfmodel.pipelined_wave_mops`` turns
-the ledger into the roofline comparison the benchmarks report (fig10).
+Observability: every wave gets a :class:`~repro.core.ledger.WaveRecord`
+in the pipeline's :class:`~repro.core.ledger.WaveLedger` (defined in
+``core.ledger`` so the store can record into it; re-exported here).  The
+record holds the issue and drain intervals, and the pipeline opens it
+around ``issue_fn`` and ``finalize_fn`` so the store's own spans record
+into it: ``phases`` sums host nanoseconds per step name within the wave,
+and ``waits`` counts the host's waits on device values.  The steps are
+``build`` (u64 split, pad, host-to-device puts), ``launch`` (the wave's
+programs enqueued), ``epilogue`` (host join and masks after the copies),
+``retry`` / ``flush`` / ``plan`` / ``stitch`` on the serial write path, and
+one ``wait.<what>`` span per wait site: ``wait.stats`` (a counter read
+back, e.g. the hot-cache hit count), ``wait.results`` (the result copies),
+``wait.scan_probe``, ``wait.shadow``, ``wait.counts``, ``wait.buffers``,
+``wait.invalidate``.  ``overlap_frac`` measures how much of the pipeline's
+busy time had >1 wave in flight (0 by construction at ``queue_depth=1``).
+Each half, and each step inside it, is a ``jax.profiler`` annotation
+(``<name>/<kind>/issue#<seq>``, ``<name>/<kind>/<step>#<seq>``), so a
+device trace shows the overlap and which step the host was in, and
+:meth:`WavePipeline.trace` captures a full profiler trace directory.
 """
 
 from __future__ import annotations
@@ -63,97 +74,13 @@ from __future__ import annotations
 import contextlib
 import time
 from collections import deque
-from dataclasses import dataclass, field
-from typing import Any, Callable, List, Optional, Tuple
+from typing import Any, Callable, List, Optional
 
 import jax
 import jax.profiler
 import numpy as np
 
-
-# ---------------------------------------------------------------------------
-# timing ledger
-# ---------------------------------------------------------------------------
-
-
-@dataclass
-class WaveRecord:
-    seq: int
-    kind: str
-    t_issue0: int  # ns, issue phase start (host build begins)
-    t_issue1: int  # ns, issue phase end (device dispatch enqueued)
-    t_drain0: int = 0  # ns, drain phase start (blocking gather begins)
-    t_drain1: int = 0  # ns, drain phase end (results on host)
-
-    @property
-    def issue_ns(self) -> int:
-        return self.t_issue1 - self.t_issue0
-
-    @property
-    def drain_ns(self) -> int:
-        return self.t_drain1 - self.t_drain0
-
-    @property
-    def inflight(self) -> Tuple[int, int]:
-        """The wave's in-flight interval: issue start -> drain end."""
-        return (self.t_issue0, self.t_drain1)
-
-
-@dataclass
-class WaveLedger:
-    """Per-wave timing ledger — the observability half of the pipeline.
-
-    ``overlap_frac`` is the measured double-buffering: the fraction of the
-    pipeline's total in-flight time covered by >= 2 concurrent waves.
-    Serial execution (queue_depth=1, or a pipeline that drains every wave
-    before issuing the next) scores exactly 0; any genuine issue-while-
-    draining overlap scores > 0."""
-
-    records: List[WaveRecord] = field(default_factory=list)
-
-    @property
-    def n_waves(self) -> int:
-        return len(self.records)
-
-    @property
-    def wave_issue_ns(self) -> int:
-        return sum(r.issue_ns for r in self.records)
-
-    @property
-    def wave_drain_ns(self) -> int:
-        return sum(r.drain_ns for r in self.records)
-
-    def overlap_frac(self) -> float:
-        """1 - merged_span / sum_of_intervals over the in-flight intervals
-        (both restricted to time the pipeline was busy at all).  Disjoint
-        intervals (pure serial) -> 0; full double-buffering -> ~0.5+."""
-        iv = sorted(r.inflight for r in self.records if r.t_drain1 > 0)
-        if not iv:
-            return 0.0
-        total = sum(b - a for a, b in iv)
-        if total <= 0:
-            return 0.0
-        merged = 0
-        cur_a, cur_b = iv[0]
-        for a, b in iv[1:]:
-            if a > cur_b:
-                merged += cur_b - cur_a
-                cur_a, cur_b = a, b
-            else:
-                cur_b = max(cur_b, b)
-        merged += cur_b - cur_a
-        return max(0.0, 1.0 - merged / total)
-
-    def summary(self) -> dict:
-        n = max(self.n_waves, 1)
-        return {
-            "waves": self.n_waves,
-            "wave_issue_ns": self.wave_issue_ns,
-            "wave_drain_ns": self.wave_drain_ns,
-            "issue_us_per_wave": self.wave_issue_ns / n / 1e3,
-            "drain_us_per_wave": self.wave_drain_ns / n / 1e3,
-            "overlap_frac": self.overlap_frac(),
-        }
+from repro.core.ledger import WaveLedger, WaveRecord, open_wave
 
 
 # ---------------------------------------------------------------------------
@@ -212,11 +139,11 @@ class WavePipeline:
             self._drain_oldest()
         seq = self._seq
         self._seq += 1
-        t0 = time.perf_counter_ns()
-        with _trace_annotation(f"{self.name}/{kind}/issue#{seq}"):
+        label = f"{self.name}/{kind}"
+        rec = WaveRecord(seq=seq, kind=kind, t_issue0=time.perf_counter_ns())
+        with _trace_annotation(f"{label}/issue#{seq}"), open_wave(label, rec):
             ctx = issue_fn()
-        t1 = time.perf_counter_ns()
-        rec = WaveRecord(seq=seq, kind=kind, t_issue0=t0, t_issue1=t1)
+        rec.t_issue1 = time.perf_counter_ns()
         ticket = WaveTicket(seq, kind, ctx, finalize_fn, rec)
         self._inflight.append(ticket)
         return ticket
@@ -224,8 +151,11 @@ class WavePipeline:
     # -------------------------------------------------------------- drain
     def _drain_oldest(self) -> None:
         ticket = self._inflight.popleft()
+        label = f"{self.name}/{ticket.kind}"
         ticket.record.t_drain0 = time.perf_counter_ns()
-        with _trace_annotation(f"{self.name}/{ticket.kind}/drain#{ticket.seq}"):
+        with _trace_annotation(f"{label}/drain#{ticket.seq}"), open_wave(
+            label, ticket.record
+        ):
             ticket._result = ticket.finalize_fn(ticket.ctx)
         ticket.record.t_drain1 = time.perf_counter_ns()
         ticket.ctx = None  # drop wave buffers: nothing may pin donated state
@@ -470,23 +400,10 @@ class PipelinedStore:
         )
 
     def result(self, ticket: WaveTicket):
-        out = self.pipeline.result(ticket)
-        self._sync_stats()
-        return out
+        return self.pipeline.result(ticket)
 
     def drain(self) -> None:
         self.pipeline.drain()
-        self._sync_stats()
-
-    def _sync_stats(self) -> None:
-        """Fold the measured ledger into the wrapped store's StoreStats so
-        the perfmodel comparison reads timing next to the byte/patch
-        counters (single-store tier; the sharded facade exposes the ledger
-        through pipeline_summary instead)."""
-        st = getattr(self.store, "stats", None)
-        if st is not None and hasattr(st, "wave_issue_ns"):
-            st.wave_issue_ns = self.ledger.wave_issue_ns
-            st.wave_drain_ns = self.ledger.wave_drain_ns
 
     # --------------------------------------------------------------- sync
     def get(

@@ -445,7 +445,8 @@ def test_queue_depth_bounds_inflight():
 def test_overlap_ledger_and_stats_sync():
     """qd=1 scores exactly 0 overlap (the serial facade); qd=2 with
     back-to-back submits measures > 0 (wave N+1's issue starts before wave
-    N's drain ends, structurally).  Ledger sums land in StoreStats."""
+    N's drain ends, structurally).  The summary's sums are the ledger's
+    records, each wave timed in both halves."""
     for qd, expect_overlap in ((1, False), (2, True)):
         store, keys = _mini_store()
         pipe = PipelinedStore(store, queue_depth=qd)
@@ -460,8 +461,10 @@ def test_overlap_ledger_and_stats_sync():
             assert s["overlap_frac"] > 0.0, s
         else:
             assert s["overlap_frac"] == 0.0, s
-        assert store.stats.wave_issue_ns == s["wave_issue_ns"]
-        assert store.stats.wave_drain_ns == s["wave_drain_ns"]
+        recs = pipe.ledger.records
+        assert s["wave_issue_ns"] == sum(r.issue_ns for r in recs)
+        assert s["wave_drain_ns"] == sum(r.drain_ns for r in recs)
+        assert all(r.t_issue1 >= r.t_issue0 and r.t_drain1 >= r.t_drain0 for r in recs)
 
 
 def test_barrier_methods_drain_first():
