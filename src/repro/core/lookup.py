@@ -15,6 +15,16 @@ insert buffer, an eps_leaf window of the key array, and one value — the two
 these touches and pushes them through the paper's latency constants, so the
 structure here *is* the performance model.
 
+Each eps window is one contiguous fetch per request: the slot's 128-key row
+(one (2, 128) tile of the pool in the TPU's layout), searched in place with
+a lane mask that also says whether the key at the found rank is the one
+asked for, so that key is not picked out again.  The segment anchor, the
+child pointer and the values are single-entry gathers, and the buffer
+entry's op a one-hot select over the op row already fetched: every gather
+of the walk has one index per request (``tests/test_tpu_compile.py`` holds
+this).  On a v5e a gather costs per index, not per byte, so one index per
+window key costs far more than the whole row.
+
 All keys are u32 limb pairs; all functions are batched over a request wave.
 """
 
@@ -70,9 +80,14 @@ def _predict(slope, anchor_hi, anchor_lo, khi, klo):
 def _window_rank(pool_keys, slot, count, pred, eps, khi, klo):
     """Index of the last key <= k inside the eps window around ``pred``.
 
-    pool_keys: (P, 128, 2); slot/count/pred/khi/klo: (B,).  Returns (B,) rank
-    (may be -1 when the key precedes the window, which only happens for keys
-    below the segment's first entry) and the window base ``lo``.
+    pool_keys: (P, 128, 2); slot/count/pred/khi/klo: (B,).  Each request
+    fetches its slot's row as one contiguous slice (one (2, 128) tile in the
+    TPU's layout of the pool) and searches the window inside it with a lane
+    mask: no gather index per window key.  Returns (B,) rank (may be -1 when
+    the key precedes the window, which only happens for keys below the
+    segment's first entry) and (B,) whether the key at ``rank`` is k.  Rank
+    lies in ``[lo - 1, lo + w)``, so on a sorted row of unique keys that is
+    whether k sits anywhere in that span: no pick of the key at ``rank``.
     """
     w = 2 * eps + 2  # floor(p) +/- eps plus rounding slack — covers the bound
     lo = jnp.clip(
@@ -80,13 +95,13 @@ def _window_rank(pool_keys, slot, count, pred, eps, khi, klo):
         0,
         jnp.maximum(count - w, 0),
     )
-    idx = lo[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]  # (B, w)
     rows = pool_keys[slot]  # (B, 128, 2)
-    wk = jnp.take_along_axis(rows, idx[:, :, None], axis=1)  # (B, w, 2)
-    le = limb_le(wk[:, :, 0], wk[:, :, 1], khi[:, None], klo[:, None])
-    in_range = idx < count[:, None]
-    c = jnp.sum((le & in_range).astype(jnp.int32), axis=1)
-    return lo + c - 1, lo
+    j = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, :]
+    span = (j >= lo[:, None] - 1) & (j < lo[:, None] + w) & (j < count[:, None])
+    le = limb_le(rows[:, :, 0], rows[:, :, 1], khi[:, None], klo[:, None])
+    eq = limb_eq(rows[:, :, 0], rows[:, :, 1], khi[:, None], klo[:, None])
+    c = jnp.sum((le & span & (j >= lo[:, None])).astype(jnp.int32), axis=1)
+    return lo + c - 1, jnp.any(eq & span, axis=1)
 
 
 def route_one_level(
@@ -98,18 +113,13 @@ def route_one_level(
     # padded segments hold KEY_MAX -> never <= a real key; segment 0 is the
     # floor for keys below the node's range.
     seg = jnp.maximum(jnp.sum(le[:, 1:].astype(jnp.int32), axis=1), 0)
-    bidx = jnp.arange(node.shape[0])
-    a_hi = sf[bidx, seg, 0]
-    a_lo = sf[bidx, seg, 1]
+    anchor = tree.node_seg_first[node, seg]  # (B, 2)
     slope = tree.node_seg_slope[node, seg]
     count = tree.node_seg_count[node, seg]
     slot = tree.node_seg_slot[node, seg]
-    pred = _predict(slope, a_hi, a_lo, khi, klo)
+    pred = _predict(slope, anchor[:, 0], anchor[:, 1], khi, klo)
     rank, _ = _window_rank(tree.pivot_keys, slot, count, pred, eps, khi, klo)
-    rank = jnp.maximum(rank, 0)
-    return jnp.take_along_axis(
-        tree.pivot_child[slot], rank[:, None], axis=1
-    )[:, 0]
+    return tree.pivot_child[slot, jnp.maximum(rank, 0)]
 
 
 @partial(jax.jit, static_argnames=("depth", "eps_inner"))
@@ -137,11 +147,8 @@ def leaf_search(
     count = tree.leaf_count[leaf]
     anchor = tree.leaf_anchor[leaf]
     pred = _predict(tree.leaf_slope[leaf], anchor[:, 0], anchor[:, 1], khi, klo)
-    rank, _ = _window_rank(tree.hbm_keys, slot, count, pred, eps_leaf, khi, klo)
-    safe = jnp.maximum(rank, 0)
-    kk = jnp.take_along_axis(tree.hbm_keys[slot], safe[:, None, None].repeat(2, -1), axis=1)[:, 0]
-    found = (rank >= 0) & limb_eq(kk[:, 0], kk[:, 1], khi, klo)
-    vv = jnp.take_along_axis(tree.hbm_vals[slot], safe[:, None, None].repeat(2, -1), axis=1)[:, 0]
+    rank, found = _window_rank(tree.hbm_keys, slot, count, pred, eps_leaf, khi, klo)
+    vv = tree.hbm_vals[slot, jnp.maximum(rank, 0)]
     return rank, found, vv[:, 0], vv[:, 1]
 
 
@@ -155,7 +162,6 @@ def ib_search(
     its newest entry; ``deleted`` = newest entry is a tombstone.
     """
     bk = ib.keys[leaf]  # (B, cap, 2)
-    bv = ib.vals[leaf]
     bop = ib.op[leaf]
     cnt = ib.count[leaf]
     cap = bk.shape[1]
@@ -166,12 +172,11 @@ def ib_search(
         & (bop != IB_EMPTY)
     )
     newest = jnp.max(jnp.where(match, pos, -1), axis=1)  # (B,)
-    has = newest >= 0
-    safe = jnp.maximum(newest, 0)
-    op = jnp.take_along_axis(bop, safe[:, None], axis=1)[:, 0]
-    v = jnp.take_along_axis(bv, safe[:, None, None].repeat(2, -1), axis=1)[:, 0]
-    present = has & (op == IB_PUT)
-    deleted = has & (op == IB_DEL)
+    # the newest match's op, IB_EMPTY (0) where nothing matches
+    op = jnp.sum(jnp.where(pos == newest[:, None], bop, 0), axis=1)
+    v = ib.vals[leaf, jnp.maximum(newest, 0)]  # the newest entry's value only
+    present = op == IB_PUT
+    deleted = op == IB_DEL
     return present, deleted, v[:, 0], v[:, 1]
 
 

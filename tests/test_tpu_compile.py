@@ -14,7 +14,9 @@ file.  The Pallas kernels are off the served path and do not lower yet;
 their cases are strict xfails, so the day one lowers the test says so.
 """
 
+import math
 import os
+import re
 
 import numpy as np
 import pytest
@@ -104,6 +106,23 @@ def _fits(compiled, limit=HBM_BYTES):
     return m
 
 
+GATHER = re.compile(r"= \w+\[([\d,]*)\]\S* gather\(.*offset_dims=\{([\d,]*)\}")
+
+
+def gather_index_rows(hlo: str):
+    """(instruction, index rows) of every gather in an optimised HLO text:
+    the rows are the product of the output's batch (non-offset) dims."""
+    out = []
+    for line in hlo.splitlines():
+        m = GATHER.search(line)
+        if m:
+            dims = [int(d) for d in m.group(1).split(",") if d]
+            offset = {int(d) for d in m.group(2).split(",") if d}
+            out.append((line.strip().split(" ")[0], math.prod(
+                d for i, d in enumerate(dims) if i not in offset)))
+    return out
+
+
 def test_get_batch_compiles_for_v5e_at_50m_keys(one_chip):
     tree, ib = store_shapes(one_chip, CONFIG.n_keys)
     k = jax.ShapeDtypeStruct((CONFIG.wave_size,), jnp.uint32, sharding=one_chip)
@@ -113,6 +132,13 @@ def test_get_batch_compiles_for_v5e_at_50m_keys(one_chip):
     ).compile()
     m = _fits(compiled)
     assert m.argument_size_in_bytes > 4 * 10**9, "pools are not 50M-key sized"
+    # every piece of the walk is one gather index per request: a gather with
+    # more index rows than the wave picks elements one index each (the eps
+    # windows cost ~12 ns per key on the chip that way)
+    gathers = gather_index_rows(compiled.as_text())
+    assert gathers, "no gather found: the HLO pattern is out of date"
+    wide = [g for g in gathers if g[1] > CONFIG.wave_size]
+    assert not wide, f"per-element gathers in get_batch: {wide}"
 
 
 def test_range_batch_loop_compiles_for_v5e_at_50m_keys(one_chip):
