@@ -7,12 +7,16 @@ plain list of ``(plane, line, name, start_ns, duration_ns)`` events, so the
 same code runs on a small recorded trace in the tests:
 
 * the traced window is the host span ``bench/window``;
-* device busy time is the union of the intervals of the events on the
-  device planes' op lines, clipped to the window; idle share is 1 minus busy
-  over the window;
+* the cell's chips are the device planes ``/device:<kind>:<n>`` with ``n``
+  below the cell's chip count; planes of other chips are left out;
+* a chip's busy time is the union of the intervals of the events on its op
+  lines, clipped to the window; busy time is their mean over the cell's
+  chips, a chip that ran no op counting as idle throughout; idle share is
+  1 minus busy over the window;
 * a program's time is the sum of the durations of its module events
-  (``jit_<name>(...)``) on the device planes' module lines;
-* each idle gap is named by the innermost host span open at its start.
+  (``jit_<name>(...)``) on the chips' module lines, over all the chips;
+* each idle gap of the first chip is named by the innermost host span open
+  at its start.
 """
 
 from __future__ import annotations
@@ -22,7 +26,7 @@ import re
 import tempfile
 from collections import defaultdict
 from dataclasses import dataclass
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 WINDOW_SPAN = "bench/window"
 MODULE_LINE = "XLA Modules"
@@ -34,6 +38,15 @@ Event = Tuple[str, str, str, float, float]  # plane, line, name, start_ns, dur_n
 
 def is_device(plane: str) -> bool:
     return plane.startswith("/device:")
+
+
+_CHIP = re.compile(r"^/device:[A-Za-z_]+:(\d+)")
+
+
+def chip_of(plane: str) -> Optional[int]:
+    """The device ordinal of a device plane, ``None`` for any other plane."""
+    m = _CHIP.match(plane)
+    return int(m.group(1)) if m else None
 
 
 def union_ns(intervals: Sequence[Tuple[float, float]]) -> float:
@@ -76,7 +89,8 @@ class Reduction:
     programs: Dict[str, Tuple[float, int]]  # name -> (ns, calls)
     top_ops: List[Tuple[str, float]]  # (op, ns), most time first
     idle_gaps: List[Tuple[str, float]]  # (host span, ns), longest first
-    n_devices: int
+    n_devices: int  # the cell's chips
+    busy_ns_per_chip: List[float]
 
     @property
     def window_s(self) -> float:
@@ -84,9 +98,8 @@ class Reduction:
 
     @property
     def busy_s(self) -> float:
-        """Busy seconds, averaged over the devices in the trace (0 when the
-        trace holds no device operation)."""
-        return self.busy_ns / max(self.n_devices, 1) / 1e9
+        """Busy seconds, averaged over the cell's chips."""
+        return self.busy_ns / self.n_devices / 1e9
 
     def program(self, name: str) -> Tuple[float, int]:
         """Device seconds and executions of the jitted program ``name``."""
@@ -97,10 +110,13 @@ class Reduction:
         return {
             "device_ops": [[n, ns / 1e9] for n, ns in self.top_ops],
             "idle_gaps": [[n, ns / 1e9] for n, ns in self.idle_gaps],
+            "busy_s_per_chip": [[f"chip{i}", ns / 1e9]
+                                for i, ns in enumerate(self.busy_ns_per_chip)],
         }
 
 
-def reduce_events(events: Sequence[Event]) -> Reduction:
+def reduce_events(events: Sequence[Event], chips: int) -> Reduction:
+    """The window's device metrics over the first ``chips`` chips."""
     spans = [(s, s + d) for p, l, n, s, d in events if n == WINDOW_SPAN and not is_device(p)]
     if not spans:
         raise ValueError(f"no {WINDOW_SPAN!r} span in the trace")
@@ -109,40 +125,40 @@ def reduce_events(events: Sequence[Event]) -> Reduction:
     op_time = defaultdict(float)
     programs: Dict[str, List[float]] = defaultdict(lambda: [0.0, 0])
     for plane, line, name, s, d in events:
-        if not is_device(plane):
+        chip = chip_of(plane)
+        if chip is None or chip >= chips:
             continue
         a, b = max(s, lo), min(s + d, hi)
         if line == OP_LINE and b > a:
-            ops_by_dev[plane].append((a, b))
+            ops_by_dev[chip].append((a, b))
             op_time[name] += b - a
         elif line == MODULE_LINE and b > a:
             m = _MODULE.match(name)
             if m:
                 programs[m.group(1)][0] += b - a
                 programs[m.group(1)][1] += 1
-    busy = sum(union_ns(iv) for iv in ops_by_dev.values())
-    # idle gaps of the first device, named by what the host was doing
+    busy_per_chip = [union_ns(ops_by_dev[c]) for c in range(chips)]
+    # idle gaps of the first chip, named by what the host was doing
     host = sorted(
         (s, s + d, _SEQ.sub("", n))
         for p, l, n, s, d in events
         if not is_device(p) and n != WINDOW_SPAN and lo <= s <= hi
     )
     named = []
-    if ops_by_dev:
-        first = sorted(ops_by_dev)[0]
-        longest = sorted(gaps(ops_by_dev[first], lo, hi), key=lambda g: g[0] - g[1])[:TOP]
-        for a, b in longest:
-            open_spans = [(s, e, n) for s, e, n in host if s <= a < e]
-            label = min(open_spans, key=lambda x: x[1] - x[0])[2] if open_spans else "none"
-            named.append((label, b - a))
+    longest = sorted(gaps(ops_by_dev[0], lo, hi), key=lambda g: g[0] - g[1])[:TOP]
+    for a, b in longest:
+        open_spans = [(s, e, n) for s, e, n in host if s <= a < e]
+        label = min(open_spans, key=lambda x: x[1] - x[0])[2] if open_spans else "none"
+        named.append((label, b - a))
     top = sorted(op_time.items(), key=lambda x: -x[1])[:TOP]
     return Reduction(
         window_ns=(lo, hi),
-        busy_ns=busy,
+        busy_ns=sum(busy_per_chip),
         programs={k: (v[0], int(v[1])) for k, v in programs.items()},
         top_ops=top,
         idle_gaps=named,
-        n_devices=len(ops_by_dev),
+        n_devices=chips,
+        busy_ns_per_chip=busy_per_chip,
     )
 
 
@@ -159,9 +175,11 @@ def load_events(path: str) -> List[Event]:
 
 
 class Tracer:
-    """A profiler session over the window, in a temporary directory."""
+    """A profiler session over the window, in a temporary directory,
+    reduced over the cell's ``chips``."""
 
-    def __init__(self):
+    def __init__(self, chips: int):
+        self.chips = chips
         self.dir = tempfile.TemporaryDirectory()
 
     def start(self) -> None:
@@ -178,6 +196,6 @@ class Tracer:
         jax.profiler.stop_trace()
         try:
             (path,) = glob.glob(f"{self.dir.name}/**/*.xplane.pb", recursive=True)
-            return reduce_events(load_events(path))
+            return reduce_events(load_events(path), self.chips)
         finally:
             self.dir.cleanup()

@@ -1,14 +1,17 @@
 """The benchmark's harness: one cell of ``BENCHMARK.json`` from its files.
 
 Everything a cell needs is found by name: its configuration in the file
-that ``BENCHMARK.json`` names, its traffic in ``bench/traffic/<traffic>.json``,
-each distribution in ``bench/gen/<gen>.py`` and each metric's reader in
+that ``BENCHMARK.json`` names, the store it deploys in
+``bench/stores/<store>.py`` (``dpastore`` where the configuration names
+none), its traffic in ``bench/traffic/<traffic>.json``, each distribution in
+``bench/gen/<gen>.py`` and each metric's reader in
 ``bench/metrics/<metric>.py``.  A later cell or metric adds files; it edits
 none of these.
 
-A run, in order: generate keys and values from the seed; bulk-load a
-``DPAStore`` through its constructor and wrap it in ``PipelinedStore``; draw
-all traffic from the seed; apply the mix's set-up writes and warm-up steps
+A run, in order: generate keys and values from the seed; draw all traffic
+from the seed; build the configuration's store on the cell's chips through
+its builder, which bulk-loads it and wraps it in ``PipelinedStore``; apply
+the mix's set-up writes and warm-up steps
 (they warm every wave shape the window uses); then run the closed-loop
 window.  The client keeps ``queue_depth`` waves in flight and submits the
 next when the oldest is delivered.  It stops submitting when ``seconds``
@@ -24,19 +27,19 @@ written keys after the window.
 
 from __future__ import annotations
 
-import dataclasses
 import importlib.util
 import json
 import time
 from collections import deque
 from dataclasses import dataclass
 from pathlib import Path
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
+STORES = BENCH / "stores"  # one builder per deployed store, by name
 
 OPS = ("get", "range", "insert", "update")
 WRITES = ("insert", "update")
@@ -206,28 +209,33 @@ class TrafficDraw:
 # ---------------------------------------------------------------------------
 
 
-def build_store(config: dict, keys, vals):
-    import jax
+@dataclass
+class Built:
+    """What a store builder (``bench/stores/<name>.py``) returns from
+    ``build(config, keys, vals, devices)``, ``devices`` being the cell's
+    chips in order: the store served, its ``PipelinedStore``, the deepest
+    index walk of its sub-stores (what ``cost.get_bytes`` counts), and
+    ``stats()``, which returns the int counters summed over every live
+    sub-store under ``StoreStats``' field names."""
 
-    from repro.core import DPAStore, TreeConfig
-    from repro.core.hotcache import CacheConfig
-    from repro.core.scancache import ScanCacheConfig
-    from repro.serving.pipeline import PipelinedStore
+    store: object
+    pipe: object
+    depth: int
+    stats: Callable[[], Dict[str, int]]
 
-    store = DPAStore(
-        keys,
-        vals,
-        TreeConfig(
-            eps_inner=config["eps_inner"],
-            eps_leaf=config["eps_leaf"],
-            ib_cap=config["ib_cap"],
-            growth=config["growth"],
-        ),
-        cache_cfg=CacheConfig() if config["hot_cache"] else None,
-        scan_cache_cfg=ScanCacheConfig() if config["scan_cache"] else None,
-    )
-    jax.block_until_ready((store.tree, store.ib))
-    return store, PipelinedStore(store, queue_depth=config["queue_depth"])
+
+def build_store(config: dict, keys, vals, devices) -> Built:
+    """The store the configuration deploys, built on ``devices``."""
+    name = config.get("store", "dpastore")
+    return load_module(STORES / f"{name}.py").build(config, keys, vals, devices)
+
+
+def memory_peaks(devices):
+    """The fullest chip's peak bytes in use and each chip's, in order
+    (``None`` where a device keeps no memory statistics)."""
+    per_chip = [(d.memory_stats() or {}).get("peak_bytes_in_use") for d in devices]
+    known = [b for b in per_chip if b is not None]
+    return (max(known) if known else None), per_chip
 
 
 @dataclass
@@ -327,7 +335,8 @@ class RunResult:
     window: Window
     log: List[Delivered]  # every delivered wave, set-up and warm-up included
     readback: Optional[Delivered]
-    peak_bytes: Optional[int]
+    peak_bytes: Optional[int]  # the fullest chip's
+    peak_bytes_per_chip: List[Optional[int]]
     keys: np.ndarray  # the loaded keys and values, for the reference
     vals: np.ndarray
     phases: Dict[str, float]  # seconds of each part of the run, in order
@@ -347,12 +356,6 @@ class CompileCounter:
     def _on(self, event: str, **_):
         if event in self.EVENTS:
             self.n += 1
-
-
-def _stats(store) -> Dict[str, int]:
-    return {
-        k: v for k, v in dataclasses.asdict(store.stats).items() if isinstance(v, int)
-    }
 
 
 def read_back(client: "Client", config: dict, traffic: dict, rng) -> Optional[Delivered]:
@@ -391,13 +394,15 @@ def read_back(client: "Client", config: dict, traffic: dict, rng) -> Optional[De
 
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
              peaks: dict) -> RunResult:
-    """Set-up, warm-up, window and read-back of one cell on the device JAX
-    gives; returns what the check and the metric readers need."""
+    """Set-up, warm-up, window and read-back of one cell on the first
+    ``cell.chips`` devices JAX gives; returns what the check and the metric
+    readers need."""
     import jax
 
     import devtrace
 
     config, traffic = cell.config, cell.traffic
+    devices = jax.devices()[: cell.chips]
     phases = {"start": time.perf_counter() - t_start}
     mark = time.perf_counter()
 
@@ -414,7 +419,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     warm = draw.steps("step", traffic["warm_steps"])
     pool = draw.steps("step", traffic["pool_steps"])
     done("traffic")
-    store, pipe = build_store(config, keys, vals)
+    built = build_store(config, keys, vals, devices)
+    pipe = built.pipe
     done("load")
     client = Client(pipe, config["queue_depth"])
     client.run_steps(setup)
@@ -423,10 +429,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     done("warm")
     counter = CompileCounter()
 
-    tracer = devtrace.Tracer() if trace else None
+    tracer = devtrace.Tracer(cell.chips) if trace else None
     if tracer:
         tracer.start()
-    n_log, n_ledger, stats0 = len(client.log), len(pipe.ledger.records), _stats(store)
+    n_log, n_ledger, stats0 = len(client.log), len(pipe.ledger.records), built.stats()
     compiles0 = counter.n
     setup_s = time.perf_counter() - t_start
     with _span("bench/window"):
@@ -440,17 +446,17 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
         client.drain()
     t_end = client.log[-1].t_done
     compiles = counter.n - compiles0
-    stats = {k: v - stats0.get(k, 0) for k, v in _stats(store).items()}
+    stats = {k: v - stats0.get(k, 0) for k, v in built.stats().items()}
     ledger = pipe.ledger.records[n_ledger:]
     reduction = tracer.stop() if tracer else None
 
     readback = read_back(client, config, traffic, rng_for(seed, TRAFFIC + 100))
     done("window_and_readback")
-    mem = jax.devices()[0].memory_stats() or {}
+    peak, peak_per_chip = memory_peaks(devices)
     window = Window(
-        config=config, depth=store.depth, setup_s=setup_s, t0=t0, t_end=t_end,
+        config=config, depth=built.depth, setup_s=setup_s, t0=t0, t_end=t_end,
         waves=client.log[n_log:], ledger=ledger, stats=stats, compiles=compiles,
         peaks=peaks, trace=reduction,
     )
-    del store, pipe
-    return RunResult(window, client.log, readback, mem.get("peak_bytes_in_use"), keys, vals, phases)
+    del built, pipe
+    return RunResult(window, client.log, readback, peak, peak_per_chip, keys, vals, phases)

@@ -112,6 +112,7 @@ def main(argv=None) -> int:
         print(f"bench: FAILED: {e}", file=sys.stderr, flush=True)
         return 1
     device["memory_peak_bytes"] = res.peak_bytes
+    device["memory_peak_bytes_per_chip"] = res.peak_bytes_per_chip
     line = {
         "correct": check.verdict(found["numbers"]),
         "attempted": window.ops(),

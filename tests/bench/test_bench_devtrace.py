@@ -44,7 +44,7 @@ def _busy_by_sweep(events, lo, hi):
 
 def test_idle_share_matches_a_sweep(events):
     lo, hi = _window(events)
-    r = devtrace.reduce_events(events)
+    r = devtrace.reduce_events(events, 1)
     assert r.n_devices == 1
     assert r.window_s == pytest.approx((hi - lo) / 1e9)
     busy = _busy_by_sweep(events, lo, hi)
@@ -54,7 +54,7 @@ def test_idle_share_matches_a_sweep(events):
 
 def test_program_time_is_the_sum_of_its_module_events(events):
     lo, hi = _window(events)
-    r = devtrace.reduce_events(events)
+    r = devtrace.reduce_events(events, 1)
     mods = [(s, d) for p, l, n, s, d in events
             if p.startswith("/device:") and l == devtrace.MODULE_LINE
             and n.startswith("jit_get_batch(") and lo <= s and s + d <= hi]
@@ -64,7 +64,7 @@ def test_program_time_is_the_sum_of_its_module_events(events):
 
 
 def test_idle_gaps_are_named_by_host_spans(events):
-    r = devtrace.reduce_events(events)
+    r = devtrace.reduce_events(events, 1)
     b = r.breakdown()
     assert 1 <= len(b["device_ops"]) <= 10 and 1 <= len(b["idle_gaps"]) <= 10
     gaps = [s for _, s in b["idle_gaps"]]
@@ -75,3 +75,42 @@ def test_idle_gaps_are_named_by_host_spans(events):
 def test_union_and_gaps_by_hand():
     assert devtrace.union_ns([(0, 10), (5, 12), (20, 25)]) == 17
     assert devtrace.gaps([(2, 4), (3, 6), (8, 9)], 0, 10) == [(0, 2), (6, 8), (9, 10)]
+
+
+def test_one_chip_reads_what_it_read_before(events):
+    """The numbers this trace gave before the reduction took a chip count."""
+    r = devtrace.reduce_events(events, 1)
+    assert r.window_ns == (52429239.0, 113829127.0)
+    assert r.busy_ns == 8484165.0 and r.busy_s == 0.008484165
+    assert r.program("get_batch") == (0.006869794, 5) and len(r.programs) == 13
+    b = r.breakdown()
+    assert b["idle_gaps"][:2] == [["PjitFunction(bitwise_and)", 0.004020478],
+                                  ["kv/get/issue", 0.003896767]]
+    assert len(b["device_ops"]) == len(b["idle_gaps"]) == 10
+    assert b["busy_s_per_chip"] == [["chip0", 0.008484165]]
+
+
+def _plane_events(chip, ops, modules=()):
+    plane = f"/device:TPU:{chip}"
+    return ([(plane, devtrace.OP_LINE, "fusion", s, d) for s, d in ops]
+            + [(plane, devtrace.MODULE_LINE, "jit_get_batch(1)", s, d) for s, d in modules])
+
+
+def test_a_chip_without_ops_counts_as_idle():
+    """Four chips, one of which runs nothing in the window, read a quarter
+    less busy than the three busy chips; a chip beyond the cell's is left
+    out."""
+    busy = ([("/host:CPU", "python", devtrace.WINDOW_SPAN, 0, 1000)]
+            + _plane_events(0, [(100, 300), (250, 500)], [(100, 550)])
+            + _plane_events(1, [(0, 400)], [(0, 400)])
+            + _plane_events(2, [(900, 200)], [(900, 100)]))
+    idle = [("/device:TPU:3", devtrace.MODULE_LINE, "jit_get_batch(1)", 2000, 50)]
+    beyond = _plane_events(4, [(0, 1000)], [(0, 1000)])
+    three = devtrace.reduce_events(busy, 3)
+    four = devtrace.reduce_events(busy + idle + beyond, 4)
+    assert three.busy_ns_per_chip == [650, 400, 100] and three.busy_s == 1150 / 3 / 1e9
+    assert four.busy_ns_per_chip == [650, 400, 100, 0]
+    assert four.n_devices == 4 and four.busy_s == pytest.approx(0.75 * three.busy_s, rel=1e-12)
+    assert four.program("get_batch") == three.program("get_batch") == (1050 / 1e9, 3)
+    assert four.idle_gaps == three.idle_gaps == [("none", 250), ("none", 100)]
+    assert [n for n, _ in four.breakdown()["busy_s_per_chip"]] == ["chip0", "chip1", "chip2", "chip3"]

@@ -21,7 +21,7 @@ def test_sound_run_is_correct_and_control_is_not(cell, run_tiny):
 
 @pytest.mark.parametrize("key_seed", [1, 2, 2**31 + 5])
 def test_sound_run_is_correct_on_other_key_sets(key_seed, run_tiny):
-    res, found = run_tiny(MIXES[0], key_seed=key_seed)
+    res, found = run_tiny(MIXES[0], config={"key_seed": key_seed})
     assert check.verdict(found["numbers"]), found
     assert found["coverage"]["reads_of_written_keys"] > 0
     base = run_tiny(MIXES[0])[0]
