@@ -25,10 +25,12 @@ hacks.
 from __future__ import annotations
 
 import contextlib
+import functools
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from . import api, hotcache, insert_buffer, lookup, patch, scancache, stitch
@@ -51,6 +53,22 @@ def _pad_pow2(n: int, minimum: int = 8) -> int:
     while p < n:
         p *= 2
     return p
+
+
+def _on_device(method):
+    """Run a store method with JAX's default device set to the store's
+    device, so every array it creates (per-wave inputs, cache and stitch
+    updates) is put there directly and its programs run there.  A store
+    without a device (``device=None``) calls straight through."""
+
+    @functools.wraps(method)
+    def placed(self, *args, **kw):
+        if self.device is None:
+            return method(self, *args, **kw)
+        with jax.default_device(self.device):
+            return method(self, *args, **kw)
+
+    return placed
 
 
 def append_range_results(keys_out, vals_out, counts, idxs, rk, rv, rc, limit):
@@ -176,7 +194,12 @@ class _RangeWave:
 
 class DPAStore:
     """Single-shard DPA-Store (the distributed wrapper lives in
-    ``repro.distributed.kvshard``)."""
+    ``repro.distributed.kvshard``).
+
+    ``device`` places the store: its tree pools, insert buffers and both
+    caches are committed to that device, every per-wave input is put on it
+    and every program of the store runs there, so no array of the store
+    crosses to another chip.  ``None`` keeps JAX's default device."""
 
     def __init__(
         self,
@@ -189,6 +212,7 @@ class DPAStore:
         batched_patch: bool = True,
         scan_cache_cfg: Optional[ScanCacheConfig] = ScanCacheConfig(),
         retain_epochs: int = 0,
+        device: Optional[jax.Device] = None,
     ):
         # batched_patch=True (default): a flush cycle plans every full leaf
         # into ONE merged stitch batch and applies it as a single COPY+CONNECT
@@ -205,34 +229,44 @@ class DPAStore:
         self.stats = StoreStats()
         self.stats.bulk_load_bytes = bulk.payload_bytes()
         self.stats.bulk_load_dpa_bytes = bulk.dpa_bytes()
-        if bulk_load_via_stitch:
-            tree0 = stitch.empty_device_tree(self.image)
-            tree0 = stitch.apply_copies(tree0, bulk)
-            self.tree, _ = stitch.apply_connects(
-                tree0,
-                lookup.make_insert_buffers(
-                    self.image.leaf_anchor.shape[0], tree_cfg.ib_cap
-                ),
-                bulk,
+        self.device = device
+        # no device: not jax.default_device(None), which would override a
+        # scope the caller holds (chip_smoke.py builds shards under one)
+        with (
+            contextlib.nullcontext() if device is None else jax.default_device(device)
+        ):
+            if bulk_load_via_stitch:
+                tree0 = stitch.empty_device_tree(self.image)
+                tree0 = stitch.apply_copies(tree0, bulk)
+                self.tree, _ = stitch.apply_connects(
+                    tree0,
+                    lookup.make_insert_buffers(
+                        self.image.leaf_anchor.shape[0], tree_cfg.ib_cap
+                    ),
+                    bulk,
+                )
+            else:
+                self.tree = self.image.to_device()
+            self.ib: InsertBuffers = lookup.make_insert_buffers(
+                self.image.leaf_anchor.shape[0], tree_cfg.ib_cap
             )
-        else:
-            self.tree = self.image.to_device()
-        self.ib: InsertBuffers = lookup.make_insert_buffers(
-            self.image.leaf_anchor.shape[0], tree_cfg.ib_cap
-        )
-        self.cache_cfg = cache_cfg
-        self.cache: Optional[CacheState] = (
-            hotcache.make_cache(cache_cfg) if cache_cfg else None
-        )
-        # Scan-anchor cache (RANGE descent skip): key -> leaf where the
-        # descent bottomed out.  Invalidation is wired through the epoch
-        # manager's quarantine listener — every leaf id a stitch cycle
-        # obsoletes is collected at defer time and its anchors dropped
-        # before the cycle ends (see _apply_scan_invalidation).
-        self.scan_cache_cfg = scan_cache_cfg
-        self.scan_cache: Optional[ScanCacheState] = (
-            scancache.make_cache(scan_cache_cfg) if scan_cache_cfg else None
-        )
+            self.cache_cfg = cache_cfg
+            self.cache: Optional[CacheState] = (
+                hotcache.make_cache(cache_cfg) if cache_cfg else None
+            )
+            # Scan-anchor cache (RANGE descent skip): key -> leaf where the
+            # descent bottomed out.  Invalidation is wired through the epoch
+            # manager's quarantine listener — every leaf id a stitch cycle
+            # obsoletes is collected at defer time and its anchors dropped
+            # before the cycle ends (see _apply_scan_invalidation).
+            self.scan_cache_cfg = scan_cache_cfg
+            self.scan_cache: Optional[ScanCacheState] = (
+                scancache.make_cache(scan_cache_cfg) if scan_cache_cfg else None
+            )
+        if device is not None:  # commit: later programs stay on this chip
+            self.tree, self.ib, self.cache, self.scan_cache = jax.device_put(
+                (self.tree, self.ib, self.cache, self.scan_cache), device
+            )
         self._stale_anchor_leaves: List[int] = []
         # retain_epochs > 0 keeps every superseded leaf version addressable
         # for that many stitch cycles: reads accept ``as_of=<epoch>`` and are
@@ -309,6 +343,7 @@ class DPAStore:
             self.stats.scan_invalidated += int(n)
 
     # ------------------------------------------- point-in-time read window
+    @_on_device
     def snapshot_epoch(self) -> int:
         """Flush staged writes and return the version epoch naming the
         current stitched state — the handle for ``as_of`` reads.  Raises
@@ -387,6 +422,7 @@ class DPAStore:
         api.reject_unknown("get", legacy)
         return self.get_finalize(self.get_issue(keys, epoch=epoch, as_of=as_of))
 
+    @_on_device
     def get_issue(
         self,
         keys,
@@ -479,6 +515,7 @@ class DPAStore:
             expired=expired,
         )
 
+    @_on_device
     def get_finalize(self, w: _GetWave) -> Tuple[np.ndarray, np.ndarray]:
         """Drain half of GET: blocking gather + host epilogue."""
         if w.hits is not None:
@@ -603,6 +640,7 @@ class DPAStore:
             return None
         return adds
 
+    @_on_device
     def write_issue(self, op: str, keys, vals=None) -> Optional[_WriteWave]:
         """Issue half of PUT/DELETE — async dispatch on the proven-safe
         fast path only.  Returns ``None`` when the wave needs the serial
@@ -657,6 +695,7 @@ class DPAStore:
             self.ttl.note_delete(keys_u64)
         return _WriteWave(n=n, status=status)
 
+    @_on_device
     def write_finalize(self, w: _WriteWave) -> np.ndarray:
         """Drain half of PUT/DELETE: gather the device statuses (all OK by
         the issue-time proof, but the device array is authoritative)."""
@@ -665,6 +704,7 @@ class DPAStore:
         with span("wait.results", waits=1):
             return np.asarray(w.status)[: w.n]
 
+    @_on_device
     def put(
         self,
         keys=None,
@@ -699,6 +739,7 @@ class DPAStore:
     insert = put
     update = put
 
+    @_on_device
     def delete(self, keys=None, *args, auto_retry: bool = True, **legacy) -> np.ndarray:
         keys = api.take_legacy("delete", legacy, keys, "keys", "keys_u64")
         api.reject_unknown("delete", legacy)
@@ -867,6 +908,7 @@ class DPAStore:
             )
         )
 
+    @_on_device
     def range_issue(
         self,
         k_min,
@@ -879,6 +921,7 @@ class DPAStore:
         start_leaves: Optional[np.ndarray] = None,
         arity: int = 3,
         as_of: Optional[int] = None,
+        pad_to: int = 0,
         _raw: bool = False,
     ) -> _RangeWave:
         """Issue half of RANGE: anchor-cache start resolution + the single
@@ -891,7 +934,12 @@ class DPAStore:
         TTL filter applies (live tracker non-empty, or the epoch's frozen
         snapshot for as_of), expiry can hollow out a full row — the wave
         then runs its refill loop synchronously at issue time and comes
-        back prebaked (``_raw=True`` is that loop's unfiltered inner call)."""
+        back prebaked (``_raw=True`` is that loop's unfiltered inner call).
+
+        The wave's programs run at ``n`` rows padded to a power of two, and
+        at no fewer than ``pad_to``: a caller that splits one client wave
+        into sub-waves gives each the client wave's shape, so the programs
+        compiled follow the client's wave sizes and not how its rows split."""
         if max_rounds is not None and max_rounds < 1:
             # NOT an assert: must survive ``python -O`` (see get_issue)
             raise ValueError(
@@ -947,7 +995,7 @@ class DPAStore:
         if start_leaves is not None:
             self.stats.range_reissue_rounds += 1
         with span("build"):
-            B = _pad_pow2(n)
+            B = _pad_pow2(max(n, pad_to))
             khi, klo, active = self._limbs(start_keys_u64, B)
             res_pad = np.full(B, -1, dtype=np.int32)
             if start_leaves is not None:
@@ -1009,6 +1057,7 @@ class DPAStore:
         self._end_wave()
         return w
 
+    @_on_device
     def range_finalize(self, w: _RangeWave) -> RangeResult:
         """Drain half of RANGE: gather, host stitch, truncation epilogue,
         and pagination cursor admission."""
@@ -1227,6 +1276,7 @@ class DPAStore:
                 leaves.append(int(leaf))
         self._patch_cycle(leaves)
 
+    @_on_device
     def flush(self) -> int:
         """Patch every non-empty insert buffer as one flush cycle."""
         with span("wait.counts", waits=1):
@@ -1436,6 +1486,7 @@ class DPAStore:
             total += int(((lk >= k_lo) & (lk < k_hi)).sum())
         return total
 
+    @_on_device
     def snapshot_slice(self, k_lo, k_hi) -> Tuple[np.ndarray, np.ndarray]:
         """Live pairs in ``[k_lo, k_hi)`` as ascending ``(keys, vals)`` —
         the copy half of a slice migration.  Flushes staged writes first so
@@ -1454,6 +1505,7 @@ class DPAStore:
             return empty, empty.copy()
         return np.concatenate(ks), np.concatenate(vs)
 
+    @_on_device
     def extract_slice(self, k_lo, k_hi) -> Tuple[np.ndarray, np.ndarray]:
         """Detach the live pairs in ``[k_lo, k_hi)``: returns them and
         removes them from this store — the retire half of a slice
@@ -1489,6 +1541,7 @@ class DPAStore:
             leaf = int(self.image.leaf_next[leaf])
         return n
 
+    @_on_device
     def compact_chain(self) -> int:
         """Remove empty leaf stubs from the chain (and their parent
         entries) as one stitch transaction — the reclaim pass that keeps
@@ -1560,6 +1613,7 @@ class DPAStore:
             node = prev
 
     # ------------------------------------------------------------ TTL sweep
+    @_on_device
     def ttl_sweep(self) -> int:
         """Physically reclaim expired keys: tombstone every key past its
         deadline, flush the tombstones through a stitch cycle, then run the
@@ -1585,6 +1639,7 @@ class DPAStore:
         free = min(len(self.image.free_leaves), len(self.image.free_slots))
         return max(0, (free // 2) * self.cfg.split_cap)
 
+    @_on_device
     def ingest_slice(
         self, keys_u64, vals_u64, wave: int = 512, splice: bool = True
     ) -> int:
@@ -1700,6 +1755,7 @@ class DPAStore:
             "dpa_resident_bytes": idx,
         }
 
+    @_on_device
     def items(self) -> Tuple[np.ndarray, np.ndarray]:
         """All live pairs in key order (stitched tree + buffered writes)."""
         base = {}

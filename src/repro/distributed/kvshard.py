@@ -53,6 +53,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import lookup
+from repro.core.ledger import span
 from repro.core.keys import limb_hash, limb_hash_np
 from repro.core.tree import DeviceTree, TreeConfig
 from repro.core.lookup import InsertBuffers
@@ -203,6 +204,15 @@ class ShardedDPAStore:
     primary (``elastic.plan_replica_remesh`` → ``snapshot_slice`` +
     ``ingest_slice``/bulk load).
 
+    ``devices`` places shard group ``s`` on ``devices[s]`` (every replica
+    of a group shares its group's device), and so does every store built
+    later for that slot: a recovered replica, an evacuation target, a
+    reshard's new group at the same shard count.  ``None`` leaves every
+    sub-store on JAX's default device.  The facade's own host work is two
+    wave spans (``core.ledger``): ``route`` (routing and the per-shard
+    scatter in the issue halves) and ``gather`` (draining the shards and
+    joining their results in the finalize halves).
+
     This is host-side orchestration (each shard is an independent
     ``DPAStore``); the device-resident wave paths are
     ``serve_wave_emulated`` / ``serve_wave_sharded`` over ``stacked()`` for
@@ -223,6 +233,7 @@ class ShardedDPAStore:
         replication: int = 1,
         watchdog=None,
         retain_epochs: int = 0,
+        devices=None,
     ):
         from repro.core.store import DPAStore
         from repro.core import pla
@@ -240,6 +251,12 @@ class ShardedDPAStore:
         assert partition == "range" or replication == 1, (
             "replication rides the range tier's epoch-versioned OwnershipTable"
         )
+        if devices is not None and len(devices) != n_shards:
+            raise ValueError(
+                f"devices: one per shard group, got {len(devices)} for "
+                f"{n_shards} shards"
+            )
+        self.devices = None if devices is None else list(devices)
         keys = np.asarray(keys, dtype=np.uint64)
         vals = np.asarray(vals, dtype=np.uint64)
         self.n_shards = n_shards
@@ -320,27 +337,30 @@ class ShardedDPAStore:
         # R identical bulk loads, so replicas start bitwise-equal and the
         # synchronous write fan-out keeps their contents that way.
         self.groups: List[List[Optional[DPAStore]]] = [
-            [self._make_store(keys[h == s], vals[h == s]) for _ in range(replication)]
+            [self._make_store(keys[h == s], vals[h == s], s) for _ in range(replication)]
             for s in range(n_shards)
         ]
 
-    def _make_store(self, keys: np.ndarray, vals: np.ndarray):
+    def _make_store(self, keys: np.ndarray, vals: np.ndarray, s: int):
+        """A sub-store for shard group ``s``, on that group's device."""
         from repro.core.store import DPAStore
 
-        st = DPAStore(keys, vals, self.cfg, **self._store_kwargs)
+        device = None if self.devices is None else self.devices[s]
+        st = DPAStore(keys, vals, self.cfg, device=device, **self._store_kwargs)
         st.ttl = self.ttl  # shared deadline sidecar (see __init__)
         return st
 
-    def _fresh_store_with(self, k: np.ndarray, v: np.ndarray):
-        """Fresh store holding exactly ``(k, v)``: ingest into an empty
-        store when headroom allows (the patch/stitch path), bulk load
-        otherwise — the recovery/reshard/evacuation build discipline."""
+    def _fresh_store_with(self, k: np.ndarray, v: np.ndarray, s: int):
+        """Fresh store for shard group ``s`` holding exactly ``(k, v)``:
+        ingest into an empty store when headroom allows (the patch/stitch
+        path), bulk load otherwise — the recovery/reshard/evacuation build
+        discipline."""
         empty = np.empty(0, dtype=np.uint64)
-        fresh = self._make_store(empty, empty)
+        fresh = self._make_store(empty, empty, s)
         if k.size and k.size <= fresh.ingest_headroom():
             fresh.ingest_slice(k, v)
         elif k.size:  # slice exceeds an empty store's free pools
-            fresh = self._make_store(k, v)
+            fresh = self._make_store(k, v, s)
         return fresh
 
     @property
@@ -558,26 +578,28 @@ class ShardedDPAStore:
         epoch is captured here — barrier ops (rebalance install, failover
         flip) drain the pipeline first, so ownership cannot move under an
         in-flight wave.  ``get() == get_finalize(get_issue())``."""
-        keys, dest = self._route(np.asarray(keys, dtype=np.uint64), epoch=epoch)
-        groups = self._groups_for_epoch(epoch)
+        with span("route"):
+            keys, dest = self._route(np.asarray(keys, dtype=np.uint64), epoch=epoch)
+            groups = self._groups_for_epoch(epoch)
+            masks = [(s, dest == s) for s in range(len(groups))]
+            masks = [(s, m, keys[m]) for s, m in masks if m.any()]
         track = groups is self.groups  # retired generation: not monitored
         parts = []
-        for s in range(len(groups)):
-            m = dest == s
-            if m.any():
-                st = self._read_store(s, epoch=epoch)
-                parts.append((s if track else -1, m, st, st.get_issue(keys[m])))
+        for s, m, sub_keys in masks:
+            st = self._read_store(s, epoch=epoch)
+            parts.append((s if track else -1, m, st, st.get_issue(sub_keys)))
         return _ShardGetWave(n=keys.size, parts=parts)
 
     def get_finalize(self, w: _ShardGetWave) -> Tuple[np.ndarray, np.ndarray]:
         vals = np.zeros(w.n, dtype=np.uint64)
         found = np.zeros(w.n, dtype=bool)
-        for s, m, st, sub in w.parts:
-            t0 = time.perf_counter()
-            v, f = st.get_finalize(sub)
-            self._note_shard_time(s, time.perf_counter() - t0)
-            vals[m] = v
-            found[m] = f
+        with span("gather"):
+            for s, m, st, sub in w.parts:
+                t0 = time.perf_counter()
+                v, f = st.get_finalize(sub)
+                self._note_shard_time(s, time.perf_counter() - t0)
+                vals[m] = v
+                found[m] = f
         self._wave_end()
         return vals, found
 
@@ -593,16 +615,14 @@ class ShardedDPAStore:
         assert op in ("put", "delete"), op
         keys = np.asarray(keys, dtype=np.uint64)
         vals_np = None if vals is None else np.asarray(vals, dtype=np.uint64)
-        dest = self.route_np(keys)
-        plans = []
-        for s in range(self.n_shards):
-            m = dest == s
-            if not m.any():
-                continue
+        with span("route"):
+            dest = self.route_np(keys)
+            plans = [(s, dest == s) for s in range(self.n_shards)]
+            plans = [(s, m) for s, m in plans if m.any()]
+        for s, m in plans:
             for r in self._in_sync(s):
                 if self.groups[s][r]._write_plan(keys[m]) is None:
                     return None
-            plans.append((s, m))
         # committed: feed the planner exactly as the serial path would
         # (skipped on fallback so the serial retry is the one that feeds it)
         if self.planner is not None and op == "put":
@@ -624,12 +644,13 @@ class ShardedDPAStore:
         from repro.core.store import STATUS_OK
 
         statuses = np.zeros(w.n, dtype=np.int32)
-        for s, m, st, sub in w.parts:
-            t0 = time.perf_counter()
-            sub_status = st.write_finalize(sub)
-            self._note_shard_time(s, time.perf_counter() - t0)
-            # pessimistic merge (max: OK=0 < RETRY), same as _write_group
-            statuses[m] = np.maximum(statuses[m], sub_status)
+        with span("gather"):
+            for s, m, st, sub in w.parts:
+                t0 = time.perf_counter()
+                sub_status = st.write_finalize(sub)
+                self._note_shard_time(s, time.perf_counter() - t0)
+                # pessimistic merge (max: OK=0 < RETRY), same as _write_group
+                statuses[m] = np.maximum(statuses[m], sub_status)
         self._wave_end()
         self.acked_writes += int((statuses == STATUS_OK).sum())
         return statuses
@@ -841,26 +862,39 @@ class ShardedDPAStore:
         if k_max is not None:
             k_max = np.broadcast_to(np.asarray(k_max, dtype=np.uint64), (n,))
         if self.partition == "range":
-            owner = self.route_np(start, epoch=epoch)
-            lb = self.ownership.lower_bounds(epoch)
-            ub = self.ownership.upper_bounds(epoch)
-            groups = self._groups_for_epoch(epoch)
+            with span("route"):
+                owner = self.route_np(start, epoch=epoch)
+                lb = self.ownership.lower_bounds(epoch)
+                ub = self.ownership.upper_bounds(epoch)
+                groups = self._groups_for_epoch(epoch)
+                n_eff = len(groups)
+                fanout = n_eff if fanout is None else fanout
+                subs = []
+                for s in range(n_eff):
+                    m = (owner <= s) & (s - owner < fanout)
+                    if not m.any():
+                        continue
+                    idxs = np.where(m)[0]
+                    sub_start = np.maximum(start[idxs], lb[s])
+                    sub_ub = np.full(idxs.size, ub[s], dtype=np.uint64)
+                    if k_max is not None:
+                        sub_ub = np.minimum(sub_ub, k_max[idxs])
+                    subs.append((s, idxs, sub_start, sub_ub))
             track = groups is self.groups
-            n_eff = len(groups)
-            fanout = n_eff if fanout is None else fanout
-            for s in range(n_eff):
-                m = (owner <= s) & (s - owner < fanout)
-                if not m.any():
-                    continue
-                idxs = np.where(m)[0]
-                sub_start = np.maximum(start[idxs], lb[s])
-                sub_ub = np.full(idxs.size, ub[s], dtype=np.uint64)
-                if k_max is not None:
-                    sub_ub = np.minimum(sub_ub, k_max[idxs])
+            pad_to = 0 if self.devices is None else n
+            for s, idxs, sub_start, sub_ub in subs:
                 serving = self._read_store(s, epoch=epoch)
+                # placed shards take the client wave's shape: how the rows
+                # split over the shards moves from wave to wave, and a
+                # sub-wave sized by its share would compile a new program
+                # whenever the share crosses a power of two.  The chips
+                # walk their sub-waves side by side, and the full fan-out
+                # gives the last shard every row, so the padding adds no
+                # wall time; on one device the walks run in turn, and it
+                # would.
                 sub = serving.range_issue(
                     sub_start, limit=limit, k_max=sub_ub,
-                    max_leaves=max_leaves, arity=6,
+                    max_leaves=max_leaves, arity=6, pad_to=pad_to,
                 )
                 w.parts.append(
                     (s if track else -1, idxs, sub_start, sub_ub, serving, sub)
@@ -880,6 +914,10 @@ class ShardedDPAStore:
         loop does (including the rare host-resume of device-round-capped
         rows, which runs synchronously on the sub-query's pinned
         replica)."""
+        with span("gather"):
+            return self._range_gather(w)
+
+    def _range_gather(self, w: _ShardRangeWave):
         from repro.core.api import RangeResult
         from repro.core.store import append_range_results
 
@@ -1281,7 +1319,9 @@ class ShardedDPAStore:
         )
         for rb in plan.rebuilds:
             k, v = self.groups[rb.group][rb.source].snapshot_slice(0, KEY_MAX)
-            self.groups[rb.group][rb.replica] = self._fresh_store_with(k, v)
+            self.groups[rb.group][rb.replica] = self._fresh_store_with(
+                k, v, rb.group
+            )
             self.ownership.restore_replica(rb.group, rb.replica)
             self.recoveries += 1
         return plan
@@ -1469,6 +1509,12 @@ class ShardedDPAStore:
         assert self.partition == "range", "resharding is a range-tier op"
         assert not self.in_handoff, "commit the open handoff first"
         assert new_shards >= 1, f"new_shards must be positive, got {new_shards}"
+        if self.devices is not None and new_shards != self.n_shards:
+            # NOT an assert: must survive ``python -O``
+            raise ValueError(
+                f"reshard {self.n_shards} -> {new_shards} shards: the fleet "
+                f"is placed on {len(self.devices)} devices, one per shard"
+            )
         if new_shards == self.n_shards and new_boundaries is None:
             return None
         self.flush()  # exact census: staged writes become stitched truth
@@ -1499,7 +1545,7 @@ class ShardedDPAStore:
             k = keys[cuts[s] : cuts[s + 1]]
             v = vals[cuts[s] : cuts[s + 1]]
             new_groups.append(
-                [self._fresh_store_with(k, v) for _ in range(self.replication)]
+                [self._fresh_store_with(k, v, s) for _ in range(self.replication)]
             )
         self._retired_groups = self.groups
         self.groups = new_groups
@@ -1570,7 +1616,7 @@ class ShardedDPAStore:
                 continue
             st.flush()
             k, v = st.snapshot_slice(0, KEY_MAX)
-            self.groups[s][r] = self._fresh_store_with(k, v)
+            self.groups[s][r] = self._fresh_store_with(k, v, s)
             moved = int(k.size)  # replicas are identical: count one copy
         self.evacuations += 1
         if self.watchdog is not None:

@@ -64,6 +64,13 @@ def _device_label() -> str:
     return f"{devs[0].platform} ({devs[0].device_kind} x{len(devs)})"
 
 
+def _shard_devices(n_shards: int):
+    """One device per shard, the first ``n_shards`` JAX gives, where the
+    host has that many; else ``None`` (every shard on the default device)."""
+    devs = jax.devices()
+    return devs[:n_shards] if len(devs) >= n_shards else None
+
+
 def _parse_tenant_map(spec: str) -> dict:
     """``'100'`` -> every tenant; ``'0:200,3:50'`` -> per-tenant overrides.
 
@@ -231,6 +238,8 @@ def serve_kv(args):
             scan_cache_cfg=scan_cfg,
             replication=args.replication,
             retain_epochs=args.retain_epochs,
+            # a fleet placed one shard per chip keeps its shard count
+            devices=None if args.reshard_to else _shard_devices(args.shards),
         )
     # queue_depth > 1: double-buffered dispatch — wave N+1 builds and
     # dispatches while wave N's gather drains; barrier ops (rebalance,
